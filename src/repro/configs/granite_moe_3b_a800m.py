@@ -1,8 +1,9 @@
 """granite-moe-3b-a800m [moe] — 40 experts top-8.
 
 32L d_model=1536 24H (GQA kv=8) d_ff=512 vocab=49155, MoE 40e top-8
-[hf:ibm-granite/granite-3.0-1b-a400m-base; hf]. Experts are zero-padded to
-a multiple of the data-axis size for EP (40 → 48 on a 16-wide axis).
+[hf:ibm-granite/granite-3.0-3b-a800m-base config.json]. Experts are
+zero-padded to a multiple of the data-axis size for EP (40 → 48 on a
+16-wide axis).
 """
 
 from repro.models.config import ArchConfig
@@ -24,5 +25,5 @@ CONFIG = ArchConfig(
     rope_theta=10000.0,
     capacity_factor=1.5,
     remat="dots",
-    source="hf:ibm-granite/granite-3.0-1b-a400m-base; hf",
+    source="hf:ibm-granite/granite-3.0-3b-a800m-base config.json",
 )

@@ -21,8 +21,8 @@ power-law head → hit latency 7 ms → 2 ms).
 
 **Quantized residency + fp32 re-rank tier.** With ``emb_dtype="int8"``
 the device-resident embedding tier is int8 (per-slot symmetric scales,
-see core/hnsw.py) — ~4x fewer bytes per sync/gather and ~4x more entries
-per quota byte. Mirroring the paper's hybrid split (compact in-memory
+see core/hnsw.py) — ~4x fewer bytes per resident row and flat-scan tile,
+~4x more entries per quota byte. Mirroring the paper's hybrid split (compact in-memory
 search structure vs external document storage), the full-precision fp32
 embedding lives NEXT TO the document in the ``DocumentStore``: a device
 result whose quantized score lands within the per-category
@@ -87,7 +87,7 @@ class SemanticCache:
     (TPU data plane); otherwise the host search is used (CPU benchmarks).
     ``emb_dtype``: the device-resident embedding dtype — "float32" (the
     exact baseline) or "int8" (quantized residency: ~4x fewer bytes per
-    sync/gather, with the fp32 re-rank tier deciding borderline matches
+    resident row, with the fp32 re-rank tier deciding borderline matches
     from the embedding stored next to the document).
     """
 

@@ -50,9 +50,12 @@ every row is ALSO quantized on write — per-slot symmetric scale,
 ``emb`` plus a per-slot fp32 ``scale`` table that rides the same
 dirty-row delta sync. All three data-plane kernels fuse the dequant into
 their dot products (asymmetric scoring: fp32 query, int8 rows, score ×
-scale after the dot), so every frontier-hop DMA, delta-sync scatter and
-flat-scan tile moves ~1/4 the bytes and a category quota holds ~4x the
-entries per HBM byte. fp32 stays the default and the exact baseline.
+scale after the dot), so every flat-scan tile moves ~1/4 the bytes and a
+category quota holds ~4x the entries per HBM byte. (Gather and scatter
+DMAs move aligned row groups — 8 fp32 or 32 int8 rows — so on the chip
+their traffic per row is the same for both dtypes; see
+kernels/gather_scores.py.) fp32 stays the default and the exact
+baseline.
 Quantization can shift a score by ~1e-3, so the cache layer re-scores
 borderline results (|score − τ| ≤ margin) from the fp32 embedding stored
 next to the document (see core/cache.py re-rank tier) — latency may
@@ -578,7 +581,10 @@ def beam_search(emb: jax.Array,          # (cap, d) float32 or int8 rows
     def score_nodes(idx):  # idx (B, K) -> cosine scores (B, K)
         safe = jnp.maximum(idx, 0)
         vecs = jnp.take(emb, safe, axis=0).astype(jnp.float32)     # (B,K,d)
-        s = jnp.einsum("bkd,bd->bk", vecs, queries)
+        # fp32 precision, as in the kernels: the fused and reference hops
+        # must rank candidates alike on the chip too.
+        s = jnp.einsum("bkd,bd->bk", vecs, queries,
+                       precision=jax.lax.Precision.HIGHEST)
         if scales is not None:      # fused per-row dequant (int8 rows)
             s = s * jnp.take(scales, safe, axis=0)
         return jnp.where(idx == INVALID, -jnp.inf, s)
@@ -718,7 +724,7 @@ class HNSWParams:
     hop_impl: str | None = None
     # Device-resident embedding dtype: "float32" (exact baseline) or
     # "int8" (per-slot symmetric scales; every kernel fuses the dequant —
-    # ~4x fewer bytes per sync scatter and per gather DMA, ~4x more
+    # ~4x fewer bytes per resident row and per flat-scan tile, ~4x more
     # entries per quota byte). The host keeps fp32 as the control plane.
     emb_dtype: str = "float32"
 

@@ -193,6 +193,34 @@ class WorkloadGenerator:
             ))
         return out
 
+    def sample_entries(self, n: int) -> tuple[np.ndarray, list[str],
+                                              np.ndarray]:
+        """n draws of the mix in bulk: (embeddings (n, dim) fp32,
+        categories, intent ids (n,)). Category shares, intent repetition
+        and paraphrase noise are the per-query stream's, vectorized — the
+        way to fill a cache at deployment scale, where ``generate``'s
+        per-query records take minutes. Static repetition only (zipf /
+        uniform): no clock, bursts, drift or content versions."""
+        cat_idx = self.rng.choice(len(self.specs), size=n, p=self._shares)
+        emb = np.empty((n, self.dim), np.float32)
+        intents = np.empty(n, np.int64)
+        for k, spec in enumerate(self.specs):
+            rows = np.where(cat_idx == k)[0]
+            kind = spec.repetition
+            if kind == "auto":
+                kind = "uniform" if spec.zipf_alpha is None else "zipf"
+            if kind == "uniform":
+                ids = self.rng.integers(0, spec.pool_size, size=rows.size)
+            elif kind == "zipf":
+                ids = self.rng.choice(spec.pool_size, size=rows.size,
+                                      p=self._zipf_probs(spec))
+            else:
+                raise ValueError(f"{spec.name}: sample_entries needs static "
+                                 f"repetition, not {kind!r}")
+            intents[rows] = ids
+            emb[rows] = self.spaces[spec.name].sample_batch(ids, self.rng)
+        return emb, [self.specs[int(k)].name for k in cat_idx], intents
+
 
 def dataclass_replace(spec: CategorySpec, **kw) -> CategorySpec:
     from dataclasses import replace

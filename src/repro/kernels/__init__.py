@@ -12,15 +12,18 @@ tiling, a jit'd wrapper in ``ops.py``, and a pure-jnp oracle in ``ref.py``:
                        embedding DMAs → masked scores; done queries issue
                        no DMAs (the lookup hot loop, §5.3)
     gather_scores    — scalar-prefetch gather + dot (entry-set scoring);
-                       ``gather_scores_masked`` fuses the per-query category
-                       mask into the same gather (§5.3)
+                       ``gather_scores_masked`` adds the per-query category
+                       mask (§5.3)
+    scatter_update   — in-place row scatter, the device tables' delta flush
     flash_attention  — tiled prefill attention (causal / sliding-window /
                        logit softcap / GQA)
     decode_attention — single-token decode against a long KV cache
     mamba_scan       — chunked selective-scan recurrence (Mamba1)
 
-Kernels target TPU (MXU-aligned tiles, VMEM budgets); on this CPU container
-they are validated with ``interpret=True`` against the oracles. Model code
-paths default to pure-jnp implementations (clean HLO for the dry-run
-roofline) and switch to kernels with ``use_pallas=True`` on real TPUs.
+Kernels target TPU (MXU-aligned tiles, VMEM budgets, tile-aligned DMAs);
+on the CPU they run with ``interpret=True`` against the oracles, and
+tests/test_chip_compile.py compiles the cache kernels for a described v5e.
+Model code paths default to pure-jnp implementations (clean HLO for the
+dry-run roofline) and switch to kernels with ``use_pallas=True`` on real
+TPUs.
 """

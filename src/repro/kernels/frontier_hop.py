@@ -7,16 +7,17 @@ XLA gather first — the candidate ids round-trip through an HBM-resident
 (B, F·M, d) gather every hop. This kernel fuses the whole hop:
 
     grid (B, F) — one step per frontier lane. The frontier ids are
-    scalar-prefetched, so each step's *neighbor row* arrives via block
-    index maps (SMEM copy for DMA addressing + VMEM copy for vector ops)
-    before the body runs. The body then issues one async DMA per live
-    candidate, pulling its embedding row and its packed validity/category
-    word straight from the HBM tables into VMEM scratch, and emits the
-    candidate ids, routing scores and result-masked scores for the merge.
+    scalar-prefetched, so each step's *neighbor rows* arrive via block
+    index maps (an SMEM copy whose elements address the DMAs, and a VMEM
+    copy for vector ops) before the body runs. The body then issues one
+    async DMA per live candidate, pulling the aligned row group that holds
+    its embedding row straight from the HBM table into VMEM
+    (``gather_scores.gather_row_dots``), and dots the selected rows with
+    the query on the MXU at fp32 precision.
 
-Candidate ids therefore never leave the chip: HBM traffic per hop is the
-candidate rows actually gathered (counted by the caller as
-``rows_gathered``), not O(B·F·M·d) materialization.
+Candidate ids therefore never leave the chip, and no (B, F·M, d) gather is
+materialized. The neighbor table is blocked 8 rows at a time (the int32
+row tile); the step reads the frontier node's row out of that block.
 
 Masking contract (shared with ``ref.frontier_hop_ref``):
 
@@ -31,17 +32,12 @@ Masking contract (shared with ``ref.frontier_hop_ref``):
   removed ones. A candidate qualifies when ``meta != TOMBSTONE`` and the
   query category matches (< 0 = wildcard).
 
-QUANT-AWARE scoring (asymmetric int8): with ``scales`` (N,) the HBM
-embedding table is int8 with per-row symmetric scales — each live
-candidate's DMA moves d + 4 bytes (int8 row + fp32 scale word) instead
-of 4·d, the row casts to fp32 in VMEM and the dot multiplies by the
-scale in-kernel. The dequant is fused: no fp32 row ever exists in HBM,
-and the scale word is PACKED next to the meta word (one (N, 2) int32
-side table, scale bits bitcast into column 1), so the quantized path
-keeps the same 2 DMAs per live candidate as the fp32 path — a 4-byte
-word would otherwise pay a whole DMA issue/wait of its own. The packing
-exists only on the quantized path (selected at trace time); fp32 keeps
-its original (N, 1) meta column.
+The kernel emits candidate ids and raw dots; the jitted wrapper applies
+the per-candidate side words on the (B, F·M) result — the int8 tier's
+per-row dequant scale (``scales`` (N,): the dot of the int8 row, widened
+in VMEM, times its scale — no fp32 row ever exists in HBM) and the meta
+mask. Those are O(B·F·M) word gathers; a DMA per 4-byte word would move a
+whole tile.
 """
 
 from __future__ import annotations
@@ -53,74 +49,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gather_scores import (gather_row_dots, gather_scratch,
+                                         pad_rows, row_group)
+
 INVALID = -1
 TOMBSTONE = -2          # packed meta word for removed (invalid) slots
+NBR_GROUP = 8           # neighbor-table rows per block (the int32 row tile)
 
 
 def _frontier_hop_kernel(frontier_ref,   # scalar-prefetch (B, F) int32
                          done_ref,       # scalar-prefetch (B,) int32
-                         qcat_ref,       # scalar-prefetch (B,) int32
-                         nbr_smem,       # (1, M) int32 — candidate ids (addresses)
-                         nbr_vmem,       # (1, M) int32 — candidate ids (vector)
+                         nbr_smem,       # (8, M) int32 — candidate ids (addresses)
+                         nbr_vmem,       # (8, M) int32 — candidate ids (vector)
+                         q_ref,          # (B, d) f32 queries, resident
                          emb_any,        # (N, d) f32/int8, HBM-resident
-                         meta_any,       # (N, 1|2) int32, HBM-resident —
-                         #                 col 0 meta word; quantized path
-                         #                 packs scale bits in col 1
-                         q_ref,          # (1, d) f32 query row
-                         ids_out, route_out, res_out,      # (1, M) blocks
-                         rows_v,         # VMEM (M, d) emb-dtype scratch
-                         meta_v,         # VMEM (M, 1|2) int32 scratch
-                         sem_rows, sem_meta,               # DMA sems (M,)
-                         *, quant: bool):
+                         ids_out, dots_out,                # (F, M) blocks
+                         rows_v, grp_v, sel_v, sem):
     b = pl.program_id(0)
     f = pl.program_id(1)
-    M = nbr_vmem.shape[1]
-    live = (frontier_ref[b, f] >= 0) & (done_ref[b] == 0)
-
-    def _copies(m, cid):
-        return (pltpu.make_async_copy(emb_any.at[pl.ds(cid, 1), :],
-                                      rows_v.at[pl.ds(m, 1), :],
-                                      sem_rows.at[m]),
-                pltpu.make_async_copy(meta_any.at[pl.ds(cid, 1), :],
-                                      meta_v.at[pl.ds(m, 1), :],
-                                      sem_meta.at[m]))
-
-    # Issue every live lane's DMAs back to back, then wait — the copies
-    # overlap each other, so the step pays max(row latencies), not the sum.
-    for m in range(M):
-        cid = nbr_smem[0, m]
-
-        @pl.when(live & (cid >= 0))
-        def _issue(m=m, cid=cid):
-            row, meta = _copies(m, cid)
-            row.start()
-            meta.start()
-    for m in range(M):
-        cid = nbr_smem[0, m]
-
-        @pl.when(live & (cid >= 0))
-        def _wait(m=m, cid=cid):
-            row, meta = _copies(m, cid)
-            row.wait()
-            meta.wait()
-
-    ids = nbr_vmem[0, :]                                   # (M,) int32
+    M = sel_v.shape[0]
+    fid = frontier_ref[b, f]
+    live = (fid >= 0) & (done_ref[b] == 0)
+    r = jnp.maximum(fid, 0) % NBR_GROUP
+    cids = [nbr_smem[r, m] for m in range(M)]
+    dots = gather_row_dots(emb_any, q_ref[pl.ds(b, 1), :], cids,
+                           [live & (cid >= 0) for cid in cids],
+                           rows_v, grp_v, sel_v, sem)
+    ids = nbr_vmem[pl.ds(r, 1), :]                          # (1, M)
     lane = live & (ids >= 0)
-    # Asymmetric scoring: the stored row (int8 on the quantized path)
-    # casts in VMEM, dots against the fp32 query, and the per-row dequant
-    # scale — bitcast back out of the packed meta row — multiplies the
-    # result after the dot.
-    dots = jnp.sum(rows_v[...].astype(jnp.float32)
-                   * q_ref[...].astype(jnp.float32), axis=1)   # (M,)
-    if quant:
-        scale = jax.lax.bitcast_convert_type(meta_v[:, 1], jnp.float32)
-        dots = dots * scale
-    qc = qcat_ref[b]
-    meta = meta_v[:, 0]
-    ok = lane & (meta != TOMBSTONE) & ((qc < 0) | (meta == qc))
-    ids_out[0, :] = jnp.where(lane, ids, INVALID)
-    route_out[0, :] = jnp.where(lane, dots, -jnp.inf)
-    res_out[0, :] = jnp.where(ok, dots, -jnp.inf)
+    ids_out[pl.ds(f, 1), :] = jnp.where(lane, ids, INVALID)
+    dots_out[pl.ds(f, 1), :] = jnp.where(lane, dots, -jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -138,57 +96,43 @@ def frontier_hop(emb: jax.Array,        # (N, d) f32 or int8, d % 128 == 0
     candidate ids (INVALID at dead lanes), routing scores (-inf at dead
     lanes only) and result scores (-inf additionally at tombstoned and
     cross-category candidates)."""
-    N, d = emb.shape
+    d = emb.shape[1]
     M = neighbors.shape[1]
     B, F = frontier.shape
-    quant = scales is not None
-    meta_col = meta.astype(jnp.int32).reshape(N, 1)
-    if quant:
-        # Pack the fp32 scale's bits next to the meta word: one (N, 2)
-        # side table, one DMA per candidate for both (a lone 4-byte
-        # scale transfer would be all DMA overhead, no payload).
-        scale_bits = jax.lax.bitcast_convert_type(
-            scales.astype(jnp.float32), jnp.int32).reshape(N, 1)
-        meta_col = jnp.concatenate([meta_col, scale_bits], axis=1)
-    mw = meta_col.shape[1]
+    G = row_group(emb.dtype)            # a multiple of NBR_GROUP
+    emb = pad_rows(emb, G)
+    neighbors = pad_rows(neighbors.astype(jnp.int32), G, INVALID)
 
-    nbr_row = lambda b, f, fr, dn, qc: (jnp.maximum(fr[b, f], 0), 0)
-    out_blk = lambda b, f, fr, dn, qc: (b, f)
+    nbr_blk = lambda b, f, fr, dn: (jnp.maximum(fr[b, f], 0) // NBR_GROUP, 0)
+    out_blk = pl.BlockSpec((None, F, M), lambda b, f, fr, dn: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(B, F),
         in_specs=[
-            # The frontier lane's neighbor row, twice: an SMEM copy whose
-            # elements can address the manual HBM DMAs, and a VMEM copy
-            # for the vectorized id/mask math.
-            pl.BlockSpec((1, M), nbr_row, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, M), nbr_row),
-            pl.BlockSpec(memory_space=pltpu.ANY),       # emb (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),       # meta[+scale] (HBM)
-            pl.BlockSpec((1, d), lambda b, f, fr, dn, qc: (b, 0)),
+            pl.BlockSpec((NBR_GROUP, M), nbr_blk, memory_space=pltpu.SMEM),
+            pl.BlockSpec((NBR_GROUP, M), nbr_blk),
+            pl.BlockSpec((B, d), lambda b, f, fr, dn: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),       # emb (HBM)
         ],
-        out_specs=[
-            pl.BlockSpec((1, M), out_blk),
-            pl.BlockSpec((1, M), out_blk),
-            pl.BlockSpec((1, M), out_blk),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((M, d), emb.dtype),
-            pltpu.VMEM((M, mw), jnp.int32),
-            pltpu.SemaphoreType.DMA((M,)),
-            pltpu.SemaphoreType.DMA((M,)),
-        ],
+        out_specs=[out_blk, out_blk],
+        scratch_shapes=gather_scratch(M, d, emb.dtype),
     )
-    ids, route, res = pl.pallas_call(
-        functools.partial(_frontier_hop_kernel, quant=quant),
+    ids, route = pl.pallas_call(
+        _frontier_hop_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, F * M), jnp.int32),
-            jax.ShapeDtypeStruct((B, F * M), jnp.float32),
-            jax.ShapeDtypeStruct((B, F * M), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((B, F, M), jnp.int32),
+                   jax.ShapeDtypeStruct((B, F, M), jnp.float32)],
         interpret=interpret,
-    )(frontier.astype(jnp.int32), done.astype(jnp.int32),
-      query_categories.astype(jnp.int32), neighbors.astype(jnp.int32),
-      neighbors.astype(jnp.int32), emb, meta_col, queries)
-    return ids, route, res
+    )(frontier.astype(jnp.int32), done.astype(jnp.int32), neighbors,
+      neighbors, queries.astype(jnp.float32), emb)
+    ids = ids.reshape(B, F * M)
+    route = route.reshape(B, F * M)
+    safe = jnp.maximum(ids, 0)
+    if scales is not None:
+        route = jnp.where(ids >= 0,
+                          route * jnp.take(scales.astype(jnp.float32), safe),
+                          -jnp.inf)
+    m = jnp.take(meta.astype(jnp.int32), safe)
+    qc = query_categories.astype(jnp.int32)[:, None]
+    ok = (ids >= 0) & (m != TOMBSTONE) & ((qc < 0) | (m == qc))
+    return ids, route, jnp.where(ok, route, -jnp.inf)

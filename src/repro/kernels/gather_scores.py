@@ -1,32 +1,30 @@
-"""Scalar-prefetch gather + dot kernel — one HNSW frontier hop (§5.3).
+"""Candidate-row gather + dot kernel — HNSW entry-set scoring (§5.3).
 
-Device beam search expands (B, K = beam·M) candidate node ids per hop and
-needs ``scores[b,k] = <emb[idx[b,k]], q[b]>``. On TPU the gather must be
-expressed as *block index maps*: the candidate ids are scalar-prefetched
-(available before the grid runs) and each grid step DMAs exactly one table
-row HBM→VMEM chosen by ``idx_ref`` — the canonical TPU embedding-gather
-pattern. Bytes touched: O(B·K·d) instead of the flat scan's O(N·d).
+Device beam search needs ``scores[b,k] = <emb[idx[b,k]], q[b]>`` for
+candidate node ids that live in an HBM-resident (N, d) table. The ids are
+scalar-prefetched (available in SMEM before the grid runs); each grid step
+(b, c) takes one chunk of KC candidates of query b and issues one async DMA
+per live candidate, all before the first wait, so the copies overlap.
 
-Grid: (B, K). Step (b, k): table row idx[b,k] (1, d) + query row b (1, d)
-→ VPU dot → out[b, k]. Tombstones/padding (idx < 0) clamp the DMA to row 0
-and the result is masked to -inf in the kernel body.
+**Aligned row groups.** The TPU lays an (N, d) table out in tiles of
+8 rows (32-bit) or 32 rows (int8), and a DMA must move whole tiles along
+the row axis. A candidate's DMA therefore moves the aligned group of
+``row_group(dtype)`` rows that holds it — 8 · 4 · d bytes for fp32,
+32 · d bytes for int8 — and the row is selected in VMEM. The group's other
+rows are traffic the chip pays for the alignment rule, not payload.
 
-``gather_scores_masked`` additionally fuses the per-query CATEGORY mask
-(§5.3) into the same kernel: each grid step also DMAs the gathered row's
-int32 category (block-index-mapped off the same prefetched ids, so the
-category table is never scanned) and compares it against the query's
-category in-kernel. Cross-category candidates score -inf — they can route
-the beam but never win result tracking — and the device data plane stays
-one kernel: gather + dot + category mask fused.
+The selected rows are dotted against the query on the MXU at fp32
+precision (``Precision.HIGHEST``; the default may round the operands to
+bf16). Bytes touched per hop stay O(B·K·group·d), independent of N.
 
-Both kernels are QUANT-AWARE (asymmetric int8 scoring): with ``scales``
-(N,) the table rows are int8 and each step also block-index-maps the
-gathered row's fp32 dequant scale off the same prefetched ids, casting
-the row in VMEM and multiplying the dot by the scale — the gather moves
-d + 4 bytes per candidate instead of 4·d, and no fp32 row ever
-round-trips through HBM. The scale operand exists ONLY on the quantized
-path (selected at trace time): the fp32 hot loop keeps its original
-two-operand grid steps and pays zero extra DMAs.
+Masking and dequant live in the jitted wrappers, on the (B, K) score
+matrix, not in the kernel: ``gather_scores`` maps padding (idx < 0) to
+-inf and — on the int8 tier, where ``scales`` (N,) holds each row's
+symmetric dequant scale — multiplies each candidate's dot by its row's
+scale (dequant is linear per row, so no fp32 table ever exists in HBM);
+``gather_scores_masked`` additionally scores cross-category candidates
+-inf (§5.3). A per-candidate 4-byte side word gathered by XLA costs
+O(B·K) words, while a DMA of it would move a whole tile.
 """
 
 from __future__ import annotations
@@ -38,29 +36,86 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+CHUNK = 32      # candidates per grid step (DMAs in flight at once)
 
-def _gather_scores_kernel(idx_ref,               # scalar-prefetched (B, K) int32
-                          row_ref, q_ref,        # (1, d) gathered row, (1, d) query
-                          out_ref):              # (1, 1)
+
+def row_group(dtype) -> int:
+    """Rows per aligned DMA group: the row tile of the TPU's HBM layout
+    (8 rows of a 32-bit type, 32 rows of int8)."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def pad_rows(x: jax.Array, mult: int, value=0) -> jax.Array:
+    """Pad axis 0 up to a multiple of ``mult`` (a no-op when aligned)."""
+    extra = (-x.shape[0]) % mult
+    if not extra:
+        return x
+    pad = [(0, extra)] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, pad, constant_values=value)
+
+
+def gather_row_dots(emb_any, q, cids, lives, rows_v, grp_v, sel_v, sem):
+    """Dot the table rows ``cids`` (scalars, one per candidate) against
+    ``q`` (1, d) fp32: returns (1, K) fp32, 0 at dead candidates (callers
+    mask). Each live candidate's aligned row group is DMA'd HBM → VMEM
+    (every copy started before any wait), its row selected into ``sel_v``
+    and the K rows dotted on the MXU. Shared by the gather and
+    frontier-hop kernels."""
+    G = rows_v.shape[1]
+
+    def copy(m, cid):
+        base = pl.multiple_of(cid - cid % G, G)
+        return pltpu.make_async_copy(emb_any.at[pl.ds(base, G), :],
+                                     rows_v.at[m], sem.at[m])
+
+    for m, (cid, live) in enumerate(zip(cids, lives)):
+        @pl.when(live)
+        def _start(m=m, cid=cid):
+            copy(m, cid).start()
+
+    for m, (cid, live) in enumerate(zip(cids, lives)):
+        @pl.when(live)
+        def _select(m=m, cid=cid):
+            copy(m, cid).wait()
+            if rows_v.dtype == jnp.float32:
+                row = rows_v[m, pl.ds(cid % G, 1), :]
+            else:   # packed rows: widen the group, then pick the row
+                grp_v[...] = rows_v[m].astype(jnp.float32)
+                row = grp_v[pl.ds(cid % G, 1), :]
+            sel_v[pl.ds(m, 1), :] = row
+
+        @pl.when(jnp.logical_not(live))
+        def _zero(m=m):
+            sel_v[pl.ds(m, 1), :] = jnp.zeros((1, sel_v.shape[1]),
+                                              jnp.float32)
+
+    return jax.lax.dot_general(
+        q.astype(jnp.float32), sel_v[...], (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def gather_scratch(K: int, d: int, dtype) -> list:
+    """VMEM/semaphore scratch for ``gather_row_dots`` over K candidates."""
+    G = row_group(dtype)
+    return [pltpu.VMEM((K, G, d), dtype),         # DMA'd row groups
+            pltpu.VMEM((G, d), jnp.float32),      # widened group (int8)
+            pltpu.VMEM((K, d), jnp.float32),      # selected rows
+            pltpu.SemaphoreType.DMA((K,))]
+
+
+def _gather_scores_kernel(idx_ref,               # scalar-prefetched (B, Kp)
+                          q_ref,                 # (B, d) queries, resident
+                          emb_any,               # (N, d) table, HBM
+                          out_ref,               # (C, KC) dots of query b
+                          rows_v, grp_v, sel_v, sem):
     b = pl.program_id(0)
-    k = pl.program_id(1)
-    raw = idx_ref[b, k]
-    dot = jnp.sum(row_ref[...].astype(jnp.float32)
-                  * q_ref[...].astype(jnp.float32))
-    out_ref[0, 0] = jnp.where(raw < 0, -jnp.inf, dot)
-
-
-def _gather_scores_quant_kernel(idx_ref,         # scalar-prefetched (B, K) int32
-                                row_ref,         # (1, d) gathered int8 row
-                                scale_ref,       # (1, 1) gathered dequant scale
-                                q_ref,           # (1, d) query row
-                                out_ref):        # (1, 1)
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    raw = idx_ref[b, k]
-    dot = jnp.sum(row_ref[...].astype(jnp.float32)
-                  * q_ref[...].astype(jnp.float32)) * scale_ref[0, 0]
-    out_ref[0, 0] = jnp.where(raw < 0, -jnp.inf, dot)
+    c = pl.program_id(1)
+    KC = sel_v.shape[0]
+    cids = [idx_ref[b, c * KC + m] for m in range(KC)]
+    out_ref[pl.ds(c, 1), :] = gather_row_dots(
+        emb_any, q_ref[pl.ds(b, 1), :], cids, [cid >= 0 for cid in cids],
+        rows_v, grp_v, sel_v, sem)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -69,69 +124,33 @@ def gather_scores(table: jax.Array, indices: jax.Array, queries: jax.Array,
                   *, interpret: bool = False) -> jax.Array:
     """table (N, d) fp32 — or int8 with ``scales`` (N,) per-row dequant
     scales — indices (B, K) int32 (−1 = padding); queries (B, d) fp32 →
-    scores (B, K) fp32 (−inf at padding)."""
-    N, d = table.shape
+    scores (B, K) fp32 (−inf at padding). d must be a multiple of 128
+    (``ops.hop_scores`` pads it)."""
     B, K = indices.shape
-
-    row_blk = pl.BlockSpec(
-        (1, d), lambda b, k, idx_ref: (jnp.maximum(idx_ref[b, k], 0), 0))
-    q_blk = pl.BlockSpec((1, d), lambda b, k, idx_ref: (b, 0))
-    if scales is None:
-        kernel, in_specs, operands = (
-            _gather_scores_kernel, [row_blk, q_blk], (table, queries))
-    else:
-        # Quantized path only: the row's scale shares the row's block
-        # index map off the prefetched ids.
-        scale_blk = pl.BlockSpec(
-            (1, 1), lambda b, k, idx_ref: (jnp.maximum(idx_ref[b, k], 0), 0))
-        kernel, in_specs, operands = (
-            _gather_scores_quant_kernel, [row_blk, scale_blk, q_blk],
-            (table, scales.astype(jnp.float32).reshape(N, 1), queries))
+    d = table.shape[1]
+    table = pad_rows(table, row_group(table.dtype))
+    KC = min(K, CHUNK)
+    C = -(-K // KC)
+    idx = jnp.pad(indices.astype(jnp.int32), ((0, 0), (0, C * KC - K)),
+                  constant_values=-1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, K),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda b, k, idx_ref: (b, k)),
+        grid=(B, C),
+        in_specs=[pl.BlockSpec((B, d), lambda b, c, i: (0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, C, KC), lambda b, c, i: (b, 0, 0)),
+        scratch_shapes=gather_scratch(KC, d, table.dtype),
     )
-    return pl.pallas_call(
-        kernel,
+    s = pl.pallas_call(
+        _gather_scores_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, C, KC), jnp.float32),
         interpret=interpret,
-    )(indices.astype(jnp.int32), *operands)
-
-
-def _gather_scores_masked_kernel(idx_ref,        # scalar-prefetched (B, K) int32
-                                 row_ref,        # (1, d) gathered table row
-                                 cat_ref,        # (1, 1) gathered row category
-                                 q_ref,          # (1, d) query row
-                                 qcat_ref,       # (1, 1) query category
-                                 out_ref):       # (1, 1)
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    raw = idx_ref[b, k]
-    dot = jnp.sum(row_ref[...].astype(jnp.float32)
-                  * q_ref[...].astype(jnp.float32))
-    qc = qcat_ref[0, 0]
-    ok = (raw >= 0) & ((qc < 0) | (cat_ref[0, 0] == qc))
-    out_ref[0, 0] = jnp.where(ok, dot, -jnp.inf)
-
-
-def _gather_scores_masked_quant_kernel(idx_ref,  # scalar-prefetched (B, K) int32
-                                       row_ref,    # (1, d) gathered int8 row
-                                       cat_ref,    # (1, 1) gathered category
-                                       scale_ref,  # (1, 1) gathered scale
-                                       q_ref,      # (1, d) query row
-                                       qcat_ref,   # (1, 1) query category
-                                       out_ref):   # (1, 1)
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    raw = idx_ref[b, k]
-    dot = jnp.sum(row_ref[...].astype(jnp.float32)
-                  * q_ref[...].astype(jnp.float32)) * scale_ref[0, 0]
-    qc = qcat_ref[0, 0]
-    ok = (raw >= 0) & ((qc < 0) | (cat_ref[0, 0] == qc))
-    out_ref[0, 0] = jnp.where(ok, dot, -jnp.inf)
+    )(idx, queries.astype(jnp.float32), table).reshape(B, C * KC)[:, :K]
+    if scales is not None:
+        s = s * jnp.take(scales.astype(jnp.float32),
+                         jnp.maximum(indices, 0))
+    return jnp.where(indices < 0, -jnp.inf, s)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -140,39 +159,14 @@ def gather_scores_masked(table: jax.Array, indices: jax.Array,
                          query_categories: jax.Array,
                          scales: jax.Array | None = None,
                          *, interpret: bool = False) -> jax.Array:
-    """Category-masked frontier hop. table (N, d) fp32 — or int8 with
+    """Category-masked entry scoring. table (N, d) fp32 — or int8 with
     ``scales`` (N,) per-row dequant scales — indices (B, K) int32 (−1 =
     padding); queries (B, d) fp32; slot_categories (N,) int32;
     query_categories (B,) int32 (−1 = wildcard) → scores (B, K) fp32
     (−inf at padding and at cross-category candidates)."""
-    N, d = table.shape
-    B, K = indices.shape
-    slot_cat = slot_categories.astype(jnp.int32).reshape(N, 1)
-    query_cat = query_categories.astype(jnp.int32).reshape(B, 1)
-
-    # Row + its category (+ its scale, quantized path only) share one
-    # block index map off the prefetched ids.
-    gathered_blk = lambda shape: pl.BlockSpec(
-        shape, lambda b, k, idx_ref: (jnp.maximum(idx_ref[b, k], 0), 0))
-    in_specs = [gathered_blk((1, d)), gathered_blk((1, 1))]
-    operands = [table, slot_cat]
-    kernel = _gather_scores_masked_kernel
-    if scales is not None:
-        in_specs.append(gathered_blk((1, 1)))
-        operands.append(scales.astype(jnp.float32).reshape(N, 1))
-        kernel = _gather_scores_masked_quant_kernel
-    in_specs += [pl.BlockSpec((1, d), lambda b, k, idx_ref: (b, 0)),
-                 pl.BlockSpec((1, 1), lambda b, k, idx_ref: (b, 0))]
-    operands += [queries, query_cat]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda b, k, idx_ref: (b, k)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
-        interpret=interpret,
-    )(indices.astype(jnp.int32), *operands)
+    s = gather_scores(table, indices, queries, scales, interpret=interpret)
+    cat = jnp.take(slot_categories.astype(jnp.int32),
+                   jnp.maximum(indices, 0))
+    qc = query_categories.astype(jnp.int32)[:, None]
+    ok = (qc < 0) | (cat == qc)
+    return jnp.where(ok, s, -jnp.inf)

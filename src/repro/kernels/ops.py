@@ -102,9 +102,9 @@ def hop_scores(table: jax.Array, indices: jax.Array, queries: jax.Array,
     Pass both or neither; exactly one raises (silent fallback to the
     unmasked gather would bypass category isolation).
 
-    With ``scales`` (N,) fp32 the table is int8 (per-row symmetric quant)
-    and the dequant fuses into the gather+dot — each candidate moves
-    d + 4 bytes instead of 4·d.
+    With ``scales`` (N,) fp32 the table is int8 (per-row symmetric quant):
+    rows are widened in VMEM and each dot is scaled by its row's scale —
+    no fp32 copy of the table exists.
     """
     interpret = _on_cpu() if interpret is None else interpret
     if (slot_categories is None) != (query_categories is None):
@@ -134,8 +134,8 @@ def frontier_hop(emb: jax.Array, neighbors: jax.Array, meta: jax.Array,
     early-exit freeze) — emit INVALID / -inf and, on the kernel path,
     issue no gather DMAs at all. ``meta`` is the packed per-slot word
     ``category if valid else -2`` (see kernels/frontier_hop.py). With
-    ``scales`` (N,) fp32 the embedding table is int8 and the per-candidate
-    DMA + in-kernel dequant move/score d + 4 bytes per row, not 4·d.
+    ``scales`` (N,) fp32 the embedding table is int8: rows are widened in
+    VMEM and the dots scaled per row.
 
     Dispatch (same pattern as ``scatter_rows``): the Pallas kernel on
     compiled backends, the vectorized jnp reference on CPU/interpret —
@@ -172,24 +172,21 @@ def scatter_rows(table: jax.Array, rows: jax.Array, vals: jax.Array,
     aliased, so only the R delta rows move — O(delta·d) HBM traffic
     instead of a full O(N·d) re-upload. Dispatch: the Pallas kernel
     serves lane-aligned 2-D tables (row width a multiple of 128 — the
-    embedding table, where ~90 % of the bytes live) on compiled backends;
-    1-D flag tables (valid/category, routed through a column view) and
-    narrow tables use the XLA in-place scatter, which is already optimal
-    for them and avoids off-lane blocks.
+    embedding table, where ~90 % of the bytes live — and a row count
+    that is a whole number of its DMA row groups) on compiled backends;
+    1-D flag tables (valid/category), narrow tables and odd row counts
+    use the XLA in-place scatter, which is already optimal for them and
+    avoids off-lane blocks.
 
     Contract (enforced by callers that pad the delta to a bucket size):
     rows >= 0, duplicate row ids carry identical vals rows.
     """
     interpret = _on_cpu() if interpret is None else interpret
-    squeeze = table.ndim == 1
-    if squeeze:
-        table = table[:, None]
-        vals = vals[:, None]
-    if interpret or table.shape[1] % 128 != 0:
-        out = _scatter_rows_xla(table, rows.astype(jnp.int32), vals)
-    else:
-        out = _su.scatter_rows(table, rows.astype(jnp.int32), vals)
-    return out[:, 0] if squeeze else out
+    rows = rows.astype(jnp.int32)
+    if (interpret or table.ndim != 2 or table.shape[1] % 128
+            or table.shape[0] % _gs.row_group(table.dtype)):
+        return _scatter_rows_xla(table, rows, vals)
+    return _su.scatter_rows(table, rows, vals)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

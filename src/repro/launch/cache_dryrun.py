@@ -118,10 +118,7 @@ def build(impl: str, multi_pod: bool, n_entries: int, batch: int,
     t0 = time.time()
     compiled = lowered.compile()
     t_compile = time.time() - t0
-    raw_cost = compiled.cost_analysis() or {}
-    if isinstance(raw_cost, (list, tuple)):    # jax ≤ 0.4.x: list per device
-        raw_cost = raw_cost[0] if raw_cost else {}
-    cost = {k: float(v) for k, v in raw_cost.items()
+    cost = {k: float(v) for k, v in (compiled.cost_analysis() or {}).items()
             if isinstance(v, (int, float))}
     hlo = compiled.as_text()
     coll = rl.collective_bytes_from_hlo(hlo)

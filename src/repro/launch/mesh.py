@@ -5,10 +5,9 @@ touches jax device state. Single pod: 16×16 = 256 chips (data, model).
 Multi-pod: 2×16×16 = 512 chips (pod, data, model) — the pod axis is outer
 data parallelism (or pipeline stages via ``pipeline_over_pod``).
 
-All mesh construction routes through ``make_mesh``, which version-guards
-the ``jax.sharding.AxisType`` API: newer JAX releases accept an
-``axis_types`` argument (we request Auto axes), older ones (e.g. 0.4.x)
-don't have the enum at all and take plain ``jax.make_mesh(shape, axes)``.
+All mesh construction routes through ``make_mesh``, which requests Auto
+axes (``jax.sharding.AxisType.Auto``): sharding is propagated by GSPMD
+unless a ``shard_map`` or constraint says otherwise.
 """
 
 from __future__ import annotations
@@ -17,13 +16,10 @@ import jax
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-guarded mesh constructor — the ONLY way this repo builds
+    """Mesh constructor with Auto axes — the ONLY way this repo builds
     meshes (tests/examples included, e.g. a (2,2,2) mini multi-pod)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ from repro.core.shard import ShardedSemanticCache
 from repro.core.policy import AdaptiveController, PolicyEngine, \
     paper_policies
 from repro.core.workload import TABLE1_WORKLOAD, WorkloadGenerator
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.model import Model
 from repro.obs import (TraceRecorder, coverage_fraction, prometheus_text,
                        span_accounting, telemetry_report)
@@ -62,7 +63,9 @@ def run_serving(cfg, *, n_requests: int, cache_kind: str = "hybrid",
                 telemetry_prom: str | None = None,
                 log=print) -> dict:
     model = Model(cfg)
-    params = model.init_params(jax.random.key(seed))
+    # Jitted init: leaves are created on the device in their final dtype
+    # (no fp32 staging copies), one group traced (transformer.init_stack).
+    params = jax.jit(model.init_params)(jax.random.key(seed))
     controller = AdaptiveController()
     policies = PolicyEngine(paper_policies(), controller=controller)
 
@@ -185,7 +188,7 @@ def main():
                     default="float32",
                     help="resident embedding tier: int8 = quantized "
                          "residency (fused-dequant kernels, ~4x fewer "
-                         "sync/gather bytes, fp32 re-rank at the τ "
+                         "bytes per resident row, fp32 re-rank at the τ "
                          "boundary)")
     ap.add_argument("--shards", type=int, default=1,
                     help="category-sharded cache tier: N device-resident "
@@ -211,6 +214,7 @@ def main():
                          "(implies tracing on)")
     args = ap.parse_args()
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

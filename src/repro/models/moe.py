@@ -32,40 +32,16 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.context import Dist
 
 
-@jax.custom_vjp
 def _ragged_dot(lhs, rhs, group_sizes):
-    """``lax.ragged_dot`` with fp32 accumulation and DTYPE-CORRECT
-    cotangents: jax ≤ 0.4.x's ragged_dot transpose returns fp32 cts for
-    bf16 operands (it ignores the operand dtype under
-    ``preferred_element_type``), which trips the cotangent-addition
-    typecheck when the same activation also feeds a bf16 path (residual
-    stream + router). The custom bwd reuses the built-in transpose, then
-    casts each ct back to its operand dtype."""
+    """Grouped GEMM with fp32 accumulation; its transpose returns
+    cotangents in the operand dtypes (bf16 in, bf16 cts out)."""
     return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=jnp.float32)
-
-
-def _ragged_dot_fwd(lhs, rhs, group_sizes):
-    return _ragged_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
-
-
-def _ragged_dot_bwd(res, ct):
-    lhs, rhs, group_sizes = res
-    _, vjp = jax.vjp(
-        lambda l, r: jax.lax.ragged_dot(
-            l, r, group_sizes, preferred_element_type=jnp.float32),
-        lhs, rhs)
-    dl, dr = vjp(ct)
-    return dl.astype(lhs.dtype), dr.astype(rhs.dtype), None
-
-
-_ragged_dot.defvjp(_ragged_dot_fwd, _ragged_dot_bwd)
 
 
 def padded_experts(n_experts: int, n_data: int) -> int:
@@ -222,7 +198,7 @@ def moe_ffn_ep(x: jax.Array, p: dict, cfg, dist: Dist,
         body = functools.partial(_moe_local, cfg=cfg, n_data=n_data,
                                  e_pad=e_pad, data_axis=dist.data_axis,
                                  model_axis=None)
-        y = shard_map(
+        y = jax.shard_map(
             body, mesh=dist.mesh,
             in_specs=(P(tok_axes, None), P(tok_axes, None),
                       P(tok_axes, None),
@@ -230,7 +206,7 @@ def moe_ffn_ep(x: jax.Array, p: dict, cfg, dist: Dist,
                       P(dist.data_axis, None, None),
                       P(dist.data_axis, None, None)),
             out_specs=P(tok_axes, None),
-            check_rep=False,
+            check_vma=False,
         )(x, ids, weights, w_gate, w_up, w_down)
         return y, aux
 
@@ -239,14 +215,14 @@ def moe_ffn_ep(x: jax.Array, p: dict, cfg, dist: Dist,
                              data_axis=dist.data_axis,
                              model_axis=dist.model_axis if dist.n_model > 1 else None,
                              rs_combine=rs)
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(P(batch, None), P(batch, None), P(batch, None),
                   P(dist.data_axis, None, dist.model_axis),
                   P(dist.data_axis, None, dist.model_axis),
                   P(dist.data_axis, dist.model_axis, None)),
         out_specs=P(batch, dist.model_axis if rs else None),
-        check_rep=False,
+        check_vma=False,
     )(x, ids, weights, w_gate, w_up, w_down)
     return y, aux
 

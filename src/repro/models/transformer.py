@@ -91,7 +91,10 @@ def init_sublayer(key, cfg, spec: SubLayerSpec) -> dict:
 
 
 def init_stack(key, cfg) -> dict:
-    """Group-stacked params: leaf shapes (n_groups, ...)."""
+    """Group-stacked params: leaf shapes (n_groups, ...). Built by a
+    ``vmap`` over the group keys, so each leaf is created stacked: no
+    per-group copies stay alive to be stacked (2x the params at peak), and
+    a jitted init traces one group, not ``n_groups``."""
     group, n_groups = layer_groups(cfg)
     keys = jax.random.split(key, n_groups)
 
@@ -100,8 +103,7 @@ def init_stack(key, cfg) -> dict:
         return {f"sub{i}": init_sublayer(sub[i], cfg, spec)
                 for i, spec in enumerate(group)}
 
-    per_group = [one_group(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+    return jax.vmap(one_group)(keys)
 
 
 # ---------------------------------------------------------------------------

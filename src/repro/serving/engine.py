@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.cache import SemanticCache
 from repro.core.embedding import FeatureHashEmbedder
+from repro.core.hnsw import _bucket_batch
 from repro.core.policy import AdaptiveController, LoadSignal
 from repro.core.shard import ShardedSemanticCache
 from repro.distributed.fault import StepWatchdog
@@ -203,13 +204,18 @@ class ServingEngine:
                 misses.append(i)
 
         if misses:
-            toks = np.zeros((len(misses), self.prompt_len), np.int32)
+            # Pad the miss batch to the search's power-of-two bucket so
+            # every miss count shares one compiled generate program; the
+            # padding rows (all-zero prompts) are sliced off.
+            toks = np.zeros((_bucket_batch(len(misses)), self.prompt_len),
+                            np.int32)
             for j, i in enumerate(misses):
                 p = batch[i].prompt_tokens[:self.prompt_len]
                 toks[j, :len(p)] = p
             with self._span("model_generate", batch=len(misses)):
                 out = np.asarray(
                     self._generate(self.params, jnp.asarray(toks)))
+            out = out[:len(misses)]
             texts = ["tok:" + ",".join(map(str, out[j]))
                      for j in range(len(misses))]
             # one batched write-back for every miss in this step

@@ -128,3 +128,17 @@ def test_cell_accounting():
     assert {a for a, s in run if s == "long_500k"} == \
         {"jamba_v0_1_52b", "falcon_mamba_7b"}
     assert len(run) + len(skip) == len(ARCH_IDS) * len(SHAPES)
+
+
+def test_ragged_dot_cotangents_keep_operand_dtypes():
+    """The MoE grouped GEMMs accumulate in fp32 from bf16 operands; their
+    transpose must hand back bf16 cotangents, or the cotangent sum with
+    the bf16 residual/router paths fails to typecheck under grad."""
+    from repro.models.moe import _ragged_dot
+    lhs = jnp.ones((16, 8), jnp.bfloat16)
+    rhs = jnp.ones((2, 8, 4), jnp.bfloat16)
+    gs = jnp.array([10, 6], jnp.int32)
+    out, vjp = jax.vjp(lambda l, r: _ragged_dot(l, r, gs), lhs, rhs)
+    dl, dr = vjp(jnp.ones_like(out))
+    assert out.dtype == jnp.float32
+    assert dl.dtype == jnp.bfloat16 and dr.dtype == jnp.bfloat16

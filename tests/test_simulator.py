@@ -263,3 +263,21 @@ def test_admission_skips_are_not_a_hit_rate_leak():
                for s in res2.per_category.values())
     # and gating strictly shrinks the resident footprint
     assert res.mean_resident_entries < res2.mean_resident_entries
+
+
+def test_sample_entries_matches_the_mix():
+    """The bulk sampler draws the Table-1 mix: category shares near the
+    traffic shares, unit-norm paraphrases near their intent's center,
+    and the same draws for the same seed."""
+    gen = WorkloadGenerator(TABLE1_WORKLOAD, seed=3)
+    emb, cats, intents = gen.sample_entries(20000)
+    assert emb.shape == (20000, 384) and emb.dtype == np.float32
+    np.testing.assert_allclose(np.linalg.norm(emb, axis=1), 1.0, atol=1e-5)
+    for spec in TABLE1_WORKLOAD:
+        rows = [i for i, c in enumerate(cats) if c == spec.name]
+        assert abs(len(rows) / 20000 - spec.traffic_share) < 0.02
+        centers = gen.spaces[spec.name].centers[intents[rows]]
+        assert np.mean(np.sum(emb[rows] * centers, axis=1)) > 0.5
+    emb2, cats2, _ = WorkloadGenerator(TABLE1_WORKLOAD,
+                                       seed=3).sample_entries(20000)
+    assert cats2 == cats and np.array_equal(emb2, emb)

@@ -95,6 +95,29 @@ def test_engine_watchdog_counts_straggler_steps(small_model, rng):
     assert wd.straggler_events == 1
 
 
+def test_engine_miss_counts_share_one_generate_program(small_model, rng):
+    """Every miss count 1..max_batch pads to one bucket, so the model's
+    generate program compiles once, and each miss still gets its own
+    response."""
+    cfg, model, params = small_model
+    policies = PolicyEngine(paper_policies())
+    cache = SemanticCache(policies, capacity=256, clock=SimClock(),
+                          index_kind="flat")
+    eng = ServingEngine(model, params, cache, max_batch=8, prompt_len=16,
+                        max_new_tokens=4)
+    served = 0
+    for n in range(1, eng.max_batch + 1):
+        for i in range(n):
+            eng.submit(f"unique question {n}-{i} about sorting",
+                       "code_generation", rng.integers(2, cfg.vocab_size, 16))
+        res = eng.step()
+        assert len(res) == n and not any(r.cached for r in res)
+        assert all(r.tokens.shape == (4,) for r in res)
+        served += n
+    assert eng.stats.served == served
+    assert eng._generate._cache_size() == 1
+
+
 def test_training_loss_decreases():
     from repro.launch.train import run_training
     cfg = get_config("llama3_2_3b").reduced(n_layers=2, d_model=128,
