@@ -1,0 +1,9 @@
+"""Share of the traced stretch in which no operation ran on the device, %
+(1 - busy / window, from the profiler trace)."""
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if not tr or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
