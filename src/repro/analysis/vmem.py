@@ -191,11 +191,15 @@ def capture_pallas_calls():
 
         return runner
 
+    # Jitted kernel wrappers keep their traces: drop them on the way in
+    # (so the fake is called) and out (so no later caller compiles zeros).
+    jax.clear_caches()
     pl_mod.pallas_call = fake_pallas_call
     try:
         yield captured
     finally:
         pl_mod.pallas_call = real
+        jax.clear_caches()
 
 
 def estimate(fn, *args, **kwargs) -> list[KernelFootprint]:

@@ -158,6 +158,8 @@ class SemanticCache:
             self.index = FlatIndex(dim, capacity, emb_dtype=emb_dtype)
         else:
             raise ValueError(f"unknown index_kind {index_kind!r}")
+        # The index's delta flush opens its span on this cache's recorder.
+        self.index.span = self._span
 
         # Per-slot metadata (§5.1: ~112 B/entry overhead). The category
         # and insertion-time tables LIVE IN THE INDEX (category is a
@@ -260,12 +262,15 @@ class SemanticCache:
         # nearer cross-category entry can route traffic but never shadows a
         # valid match (the seed's "category_mismatch" false-miss path is
         # gone by construction).
-        # Span "search" covers the search-latency charge, the index
-        # traversal and the single device→host sync; the fp32 re-rank
+        # Span "search" covers the search-latency charge (leaf
+        # "clock_charge"), the index traversal (with its leaf
+        # "delta_flush" when rows changed since the last search) and the
+        # single device→host sync (leaf "device_wait"); the fp32 re-rank
         # tier gets a SIBLING span so its borderline store fetches are
         # attributed separately from the traversal.
         with self._span("search", batch=len(active)):
-            self.clock.advance(self.search_ms / 1e3)
+            with self._span("clock_charge", ms=self.search_ms):
+                self.clock.advance(self.search_ms / 1e3)
             q = embeddings[active]
             taus = np.asarray([effective[i].threshold for i in active],
                               np.float32)
@@ -281,9 +286,10 @@ class SemanticCache:
                 d_idx, d_score, d_cls, d_cand = self.index.search_classified(
                     q, taus, categories=qcats, ttls=ttls, now=now)
                 ls = self.index.last_search
-                idxs, scores, cls, cands, hops, rows = jax.device_get(
-                    (d_idx, d_score, d_cls, d_cand, ls.get("hops", 0),
-                     ls.get("rows_gathered", 0)))
+                with self._span("device_wait", batch=len(active)):
+                    idxs, scores, cls, cands, hops, rows = jax.device_get(
+                        (d_idx, d_score, d_cls, d_cand, ls.get("hops", 0),
+                         ls.get("rows_gathered", 0)))
                 idxs = np.asarray(idxs, np.int64)
                 scores = np.asarray(scores, np.float64)
                 cls = np.array(cls)    # writable: the re-rank tier may edit
@@ -534,10 +540,12 @@ class SemanticCache:
                                       "insert_rejects": B}
             return slots_out
 
-        # Span "gate": the batched write-round charge plus the admission
-        # sketch pass — everything that decides WHAT gets to spend quota.
+        # Span "gate": the batched write-round charge (leaf
+        # "clock_charge") plus the admission sketch pass — everything
+        # that decides WHAT gets to spend quota.
         with self._span("gate", batch=len(admitted)):
-            self.clock.advance(self.insert_ms / 1e3)  # one batched write round
+            with self._span("clock_charge", ms=self.insert_ms):
+                self.clock.advance(self.insert_ms / 1e3)  # one write round
             now = self._now()
             cids = {c: self._cat_id(c) for c in eff}
 

@@ -80,6 +80,7 @@ import numpy as np
 
 from repro.kernels import ops
 from repro.kernels.frontier_hop import TOMBSTONE
+from repro.obs.trace import NULL_SPAN
 
 INVALID = -1
 
@@ -197,6 +198,12 @@ class DeviceResidentIndex:
     ``_rebuild_threshold()`` and (optionally) ``_finish_sync()`` for
     state that rides along on every sync (the HNSW entry set)."""
 
+    @staticmethod
+    def span(stage: str, **attrs):
+        """A span on the owning cache's recorder: ``SemanticCache`` binds
+        its own ``_span`` here. Unowned, or with no recorder, no span."""
+        return NULL_SPAN
+
     def _init_residency(self, emb_dtype: str = "float32") -> None:
         if emb_dtype not in ("float32", "int8"):
             raise ValueError(f"emb_dtype must be 'float32' or 'int8', "
@@ -294,28 +301,37 @@ class DeviceResidentIndex:
         (O(delta) bytes); a full O(capacity) upload happens only on first
         use or when the dirty fraction exceeds the rebuild threshold.
         Returned buffers are donated to the NEXT flush — re-fetch after
-        any mutation, never cache them caller-side.
+        any mutation, never cache them caller-side. With a recorder
+        attached, each flush is a ``delta_flush`` span: ``rows`` distinct
+        dirty rows, ``bucket`` rows moved (padded; capacity when full),
+        ``full`` 1 for a full upload.
         """
         if self._device is not None and self._device_version == self._version:
             return self._device
-        try:
-            self._device = _flush_device_tables(
-                self._device, self._host_tables(), self._dirty, self.capacity,
-                self._rebuild_threshold(), self._row_nbytes(),
-                self.emb_row_nbytes(), self.sync_stats)
-        except BaseException:
-            # A flush that dies mid-delta (device OOM, injected fault)
-            # may have DONATED some of the old mirror's buffers to
-            # scatters that never completed — the old self._device can
-            # no longer be trusted. Drop it so the retry rebuilds the
-            # mirror from the (authoritative, untouched) host tables
-            # with a clean full upload; the dirty log is preserved
-            # unconsumed. tests/test_coherence.py injects exactly this
-            # and checks the retried flush restores exact table
-            # equality.
-            self._device = None
-            raise
-        self._finish_sync(self._device)
+        rows, st = len(self._dirty), self.sync_stats
+        synced, uploads = st["rows_synced"], st["full_uploads"]
+        with self.span("delta_flush", rows=rows) as sp:
+            try:
+                self._device = _flush_device_tables(
+                    self._device, self._host_tables(), self._dirty,
+                    self.capacity, self._rebuild_threshold(),
+                    self._row_nbytes(), self.emb_row_nbytes(),
+                    self.sync_stats)
+            except BaseException:
+                # A flush that dies mid-delta (device OOM, injected fault)
+                # may have DONATED some of the old mirror's buffers to
+                # scatters that never completed — the old self._device
+                # can no longer be trusted. Drop it so the retry rebuilds
+                # the mirror from the (authoritative, untouched) host
+                # tables with a clean full upload; the dirty log is
+                # preserved unconsumed. tests/test_coherence.py injects
+                # exactly this and checks the retried flush restores
+                # exact table equality.
+                self._device = None
+                raise
+            sp.set(bucket=st["rows_synced"] - synced,
+                   full=st["full_uploads"] - uploads)
+            self._finish_sync(self._device)
         self._dirty.clear()
         self._device_version = self._version
         return self._device

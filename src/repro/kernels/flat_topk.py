@@ -135,6 +135,7 @@ def flat_topk(table: jax.Array, valid: jax.Array, queries: jax.Array,
 
     score, idx = pl.pallas_call(
         _flat_topk_kernel,
+        name="flat_topk",  # the device trace's instruction name
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # table tile

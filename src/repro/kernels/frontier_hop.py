@@ -119,6 +119,7 @@ def frontier_hop(emb: jax.Array,        # (N, d) f32 or int8, d % 128 == 0
     )
     ids, route = pl.pallas_call(
         _frontier_hop_kernel,
+        name="frontier_hop",  # the device trace's instruction name
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, F, M), jnp.int32),
                    jax.ShapeDtypeStruct((B, F, M), jnp.float32)],

@@ -126,6 +126,14 @@ def gather_scores(table: jax.Array, indices: jax.Array, queries: jax.Array,
     scales — indices (B, K) int32 (−1 = padding); queries (B, d) fp32 →
     scores (B, K) fp32 (−inf at padding). d must be a multiple of 128
     (``ops.hop_scores`` pads it)."""
+    return _gather_scores(table, indices, queries, scales, interpret,
+                          "gather_scores")
+
+
+def _gather_scores(table, indices, queries, scales, interpret: bool,
+                   name: str) -> jax.Array:
+    """``gather_scores``' body; ``name`` is the kernel's instruction name
+    in a device trace."""
     B, K = indices.shape
     d = table.shape[1]
     table = pad_rows(table, row_group(table.dtype))
@@ -145,6 +153,7 @@ def gather_scores(table: jax.Array, indices: jax.Array, queries: jax.Array,
         _gather_scores_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, C, KC), jnp.float32),
+        name=name,
         interpret=interpret,
     )(idx, queries.astype(jnp.float32), table).reshape(B, C * KC)[:, :K]
     if scales is not None:
@@ -164,7 +173,8 @@ def gather_scores_masked(table: jax.Array, indices: jax.Array,
     padding); queries (B, d) fp32; slot_categories (N,) int32;
     query_categories (B,) int32 (−1 = wildcard) → scores (B, K) fp32
     (−inf at padding and at cross-category candidates)."""
-    s = gather_scores(table, indices, queries, scales, interpret=interpret)
+    s = _gather_scores(table, indices, queries, scales, interpret,
+                       "gather_scores_masked")
     cat = jnp.take(slot_categories.astype(jnp.int32),
                    jnp.maximum(indices, 0))
     qc = query_categories.astype(jnp.int32)[:, None]

@@ -111,6 +111,7 @@ def scatter_rows(table: jax.Array, rows: jax.Array, vals: jax.Array,
     )
     return pl.pallas_call(
         _scatter_rows_kernel,
+        name="scatter_rows",  # the device trace's instruction name
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, d), table.dtype),
         input_output_aliases={2: 0},      # table (after rows and vals)

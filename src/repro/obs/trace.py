@@ -23,6 +23,15 @@ Span-accounting invariant (enforced by ``check_span_accounting``):
 
 Under ``WallClock`` real time accrues between spans, so the equality
 becomes a coverage fraction — report it, never assert it.
+
+Profiler channel: every span the recorder opens also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<stage>`` (its scalar
+attributes, category and shard as the event's stats), closed with the
+span. When a profiler trace is running, program stages then sit on the
+device timeline, on the profiler's clock, nested inside whatever host
+annotations enclose the call; when none is, an annotation costs about a
+microsecond. ``record`` adds a span whose start lies in the past (a
+request's queue wait): the recorder keeps it, the profiler cannot.
 """
 
 from __future__ import annotations
@@ -30,9 +39,13 @@ from __future__ import annotations
 import json
 from dataclasses import dataclass, field
 
+import numpy as np
+from jax.profiler import TraceAnnotation
+
 from repro.obs.hist import HistogramSet
 
 NO_PARENT = -1
+PROFILER_PREFIX = "repro."
 
 
 @dataclass
@@ -54,23 +67,40 @@ class Event:
     fields: dict
 
 
+def _scalars(attrs: dict) -> dict:
+    """The attributes a profiler event can carry as stats."""
+    out = {}
+    for k, v in attrs.items():
+        if isinstance(v, np.generic):
+            v = v.item()
+        if isinstance(v, (int, float, str)):
+            out[k] = v
+    return out
+
+
 class _SpanHandle:
-    """Context manager for one live span; ``set()`` adds attributes."""
+    """Context manager for one live span; ``set()`` adds attributes (to
+    the profiler event too)."""
 
-    __slots__ = ("_rec", "span")
+    __slots__ = ("_rec", "span", "_ann")
 
-    def __init__(self, rec: "TraceRecorder", span: Span) -> None:
+    def __init__(self, rec: "TraceRecorder", span: Span,
+                 ann: TraceAnnotation) -> None:
         self._rec = rec
         self.span = span
+        self._ann = ann
 
     def set(self, **attrs) -> None:
         self.span.attrs.update(attrs)
+        meta = _scalars(attrs)
+        if meta:
+            self._ann.set_metadata(**meta)
 
     def __enter__(self) -> "_SpanHandle":
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self._rec._close(self.span)
+        self._rec._close(self.span, self._ann)
         return False
 
 
@@ -108,22 +138,44 @@ class TraceRecorder:
     def span(self, stage: str, *, category: str = "", shard: int = -1,
              **attrs) -> _SpanHandle:
         parent = self._stack[-1] if self._stack else NO_PARENT
+        meta = _scalars(attrs)
+        if category:
+            meta["category"] = category
+        if shard >= 0:
+            meta["shard"] = shard
+        ann = TraceAnnotation(PROFILER_PREFIX + stage, **meta)
+        ann.__enter__()
         sp = Span(len(self.spans), parent, stage, category, shard,
                   self.clock.now(), attrs=dict(attrs))
         self.spans.append(sp)
         self._stack.append(sp.span_id)
         self.opened += 1
-        return _SpanHandle(self, sp)
+        return _SpanHandle(self, sp, ann)
 
-    def _close(self, sp: Span) -> None:
+    def _close(self, sp: Span, ann: TraceAnnotation) -> None:
         # ``with`` blocks unwind LIFO even under exceptions, so the
         # closing span is always the top of the stack.
         if self._stack and self._stack[-1] == sp.span_id:
             self._stack.pop()
         sp.dur_ms = (self.clock.now() - sp.t0) * 1e3
+        ann.__exit__(None, None, None)
         self.closed += 1
         self.hist.observe(sp.stage, sp.dur_ms,
                           category=sp.category, shard=sp.shard)
+
+    def record(self, stage: str, t0: float, t1: float, *,
+               category: str = "", shard: int = -1, **attrs) -> Span:
+        """A closed span over ``[t0, t1]`` on the recorder's clock, begun
+        before this call (a request's wait in a queue). It is a root: it
+        belongs to no call tree now open, and the profiler, which takes
+        no event after the fact, never sees it."""
+        sp = Span(len(self.spans), NO_PARENT, stage, category, shard, t0,
+                  (t1 - t0) * 1e3, dict(attrs))
+        self.spans.append(sp)
+        self.opened += 1
+        self.closed += 1
+        self.hist.observe(stage, sp.dur_ms, category=category, shard=shard)
+        return sp
 
     # -- events & direct histogram feed --------------------------------
     def event(self, name: str, **fields) -> None:

@@ -45,7 +45,8 @@ class Request:
     category: str
     prompt_tokens: np.ndarray           # (S,) int32
     max_new_tokens: int = 16
-    arrival: float = 0.0
+    arrival: float = 0.0                # submit, time.monotonic
+    traced_at: float = 0.0              # submit, on the recorder's clock
 
 
 @dataclass
@@ -128,9 +129,14 @@ class ServingEngine:
         cfg = model.cfg
         max_len = prompt_len + max_new_tokens
 
+        # The named scopes label each phase's device ops in a profile
+        # (metadata only: the program is the same without them).
         def generate(params, tokens):
-            logits, cache_, kv_len = model.prefill(
-                params, {"tokens": tokens}, max_len)
+            with jax.named_scope("prefill"):
+                logits, cache_, kv_len = model.prefill(
+                    params, {"tokens": tokens}, max_len)
+                tok0 = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1
+                                  ).astype(jnp.int32)
 
             def body(carry, _):
                 cache_, kv_len, tok = carry
@@ -140,11 +146,10 @@ class ServingEngine:
                                  ).astype(jnp.int32)
                 return (cache_, kv_len, tok), tok
 
-            tok0 = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1
-                              ).astype(jnp.int32)
-            (_, _, _), toks = jax.lax.scan(
-                body, (cache_, kv_len, tok0), None,
-                length=self.max_new - 1)
+            with jax.named_scope("decode"):
+                (_, _, _), toks = jax.lax.scan(
+                    body, (cache_, kv_len, tok0), None,
+                    length=self.max_new - 1)
             return jnp.concatenate([tok0[None], toks], axis=0).T  # (B, new)
 
         self._generate = jax.jit(generate)
@@ -163,7 +168,8 @@ class ServingEngine:
             req_id=rid, text=text, category=category,
             prompt_tokens=np.asarray(prompt_tokens, np.int32),
             max_new_tokens=max_new_tokens or self.max_new,
-            arrival=time.monotonic()))
+            arrival=time.monotonic(),
+            traced_at=self.obs.clock.now() if self.obs is not None else 0.0))
         return rid
 
     def step(self) -> list[Response]:
@@ -178,7 +184,13 @@ class ServingEngine:
         self.watchdog.step_start()
         batch = self.queue[:self.max_batch]
         self.queue = self.queue[self.max_batch:]
-        t0 = time.monotonic()
+        if self.obs is not None:
+            # each request's wait for the step in flight, submit to now,
+            # on the recorder's clock (latencies stay on time.monotonic)
+            t = self.obs.clock.now()
+            for req in batch:
+                self.obs.record("queue_wait", req.traced_at, t,
+                                category=req.category)
 
         with self._span("embed", batch=len(batch)):
             embs = self.embedder.embed_batch([r.text for r in batch])
@@ -188,17 +200,19 @@ class ServingEngine:
             self.stats.search_hops += ls.get("hops", 0)
             self.stats.rows_gathered += ls.get("rows_gathered", 0)
 
+        # Latencies are stamped when the step returns (below): a hit is
+        # handed back with its step's misses, not when it was found.
         responses: list[Response] = []
+        served: list[Request] = []
         misses: list[int] = []
         for i, (req, res) in enumerate(zip(batch, results)):
             if res.hit:
-                lat = (time.monotonic() - req.arrival) * 1e3
                 responses.append(Response(req.req_id, res.response, None,
-                                          True, lat, req.category,
+                                          True, 0.0, req.category,
                                           reason=res.reason))
+                served.append(req)
                 self.stats.served += 1
                 self.stats.cache_hits += 1
-                self.stats.total_latency_ms += lat
                 self.stats.count_reason(res.reason)
             else:
                 misses.append(i)
@@ -212,7 +226,11 @@ class ServingEngine:
             for j, i in enumerate(misses):
                 p = batch[i].prompt_tokens[:self.prompt_len]
                 toks[j, :len(p)] = p
-            with self._span("model_generate", batch=len(misses)):
+            prompt_tokens = sum(min(len(batch[i].prompt_tokens),
+                                    self.prompt_len) for i in misses)
+            with self._span("model_generate", batch=len(misses),
+                            bucket=toks.shape[0],
+                            prompt_tokens=prompt_tokens):
                 out = np.asarray(
                     self._generate(self.params, jnp.asarray(toks)))
             out = out[:len(misses)]
@@ -224,17 +242,21 @@ class ServingEngine:
                 [batch[i].text for i in misses], texts)
             for j, i in enumerate(misses):
                 req = batch[i]
-                text = texts[j]
-                lat = (time.monotonic() - req.arrival) * 1e3
-                responses.append(Response(req.req_id, text, out[j], False,
-                                          lat, req.category, reason="model"))
+                responses.append(Response(req.req_id, texts[j], out[j],
+                                          False, 0.0, req.category,
+                                          reason="model"))
+                served.append(req)
                 self.stats.served += 1
                 self.stats.model_tokens += out.shape[1]
-                self.stats.total_latency_ms += lat
                 self.stats.count_reason("model")
-                if self.controller is not None:
-                    self.controller.observe(self.model_name, LoadSignal(
-                        latency_ms=lat, queue_depth=len(self.queue)))
+        t_end = time.monotonic()
+        for resp, req in zip(responses, served):
+            resp.latency_ms = (t_end - req.arrival) * 1e3
+            self.stats.total_latency_ms += resp.latency_ms
+            if self.controller is not None and not resp.cached:
+                self.controller.observe(self.model_name, LoadSignal(
+                    latency_ms=resp.latency_ms,
+                    queue_depth=len(self.queue)))
         self.watchdog.step_end()
         self.stats.straggler_steps = self.watchdog.straggler_events
         return responses

@@ -478,3 +478,274 @@ class TestSpanLint:
 
     def test_real_traced_modules_clean(self):
         assert span_lint.lint_paths() == []
+
+
+# --------------------------------------------------- the profiler channel
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a profiler trace; return the trace's events as
+    (plane, line, name, start_ns, end_ns, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                out.append((plane.name, line.name, e.name, int(e.start_ns),
+                            int(e.start_ns + e.duration_ns), dict(e.stats)))
+    return out
+
+
+def _named(events, prefix):
+    return [e for e in events if e[2].startswith(prefix)]
+
+
+class TestProfilerChannel:
+    def test_spans_are_profiler_events_inside_bench_annotations(
+            self, tmp_path):
+        """Each span is a ``repro.<stage>`` host event with its scalar
+        attributes as stats, nested in the enclosing ``bench.*``
+        annotation, on the same ns base as the ops it waits on."""
+        import jax
+        import jax.numpy as jnp
+        f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+        x = jnp.ones((256, 256))
+        f(x).block_until_ready()
+        rec = TraceRecorder(SimClock())
+
+        def body():
+            with jax.profiler.TraceAnnotation("bench.lookup_batch"):
+                with rec.span("lookup", batch=7, category="a", shard=2):
+                    with rec.span("device_wait", batch=7,
+                                  note=[1, 2]) as sp:
+                        f(x).block_until_ready()
+                        sp.set(rows=3)
+        ev = _profile(tmp_path, body)
+        (outer,) = _named(ev, "bench.lookup_batch")
+        (look,) = _named(ev, "repro.lookup")
+        (wait,) = _named(ev, "repro.device_wait")
+        assert look[5]["batch"] == 7 and look[5]["category"] == "a"
+        assert look[5]["shard"] == 2
+        assert wait[5]["batch"] == 7 and wait[5]["rows"] == 3
+        assert "note" not in wait[5]         # not a scalar: left out
+        assert outer[3] <= look[3] <= wait[3] <= wait[4] <= look[4] \
+            <= outer[4]
+        ops = [e for e in ev if "hlo_op" in e[5]]
+        assert ops and all(wait[3] <= o[3] <= o[4] <= wait[4] for o in ops)
+        # the recorder's own record is unchanged by the channel
+        assert [sp.stage for sp in rec.spans] == ["lookup", "device_wait"]
+        assert rec.spans[1].attrs == {"batch": 7, "note": [1, 2],
+                                      "rows": 3}
+
+    @pytest.mark.parametrize("traced", [False, True])
+    def test_only_an_attached_recorder_emits_repro_events(self, tmp_path,
+                                                           traced):
+        clock = SimClock()
+        rec = TraceRecorder(clock) if traced else None
+        cache = SemanticCache(_policies(), dim=DIM, capacity=64,
+                              clock=clock, seed=0, index_kind="flat",
+                              use_device=True, obs=rec)
+        v = _bank(5, 8)
+
+        def body():
+            cache.insert_batch(v, ["a"] * 8, [f"q{i}" for i in range(8)],
+                               [f"r{i}" for i in range(8)])
+            cache.lookup_batch(v[:4], ["a"] * 4)
+        names = {e[2] for e in _named(_profile(tmp_path, body), "repro.")}
+        if traced:
+            assert {"repro.insert", "repro.evict", "repro.lookup",
+                    "repro.search", "repro.delta_flush",
+                    "repro.device_wait", "repro.clock_charge"} <= names
+        else:
+            assert names == set()
+
+    def test_record_adds_a_closed_root_span(self):
+        clock = SimClock(10.0)
+        rec = TraceRecorder(clock)
+        with rec.span("engine_step"):
+            sp = rec.record("queue_wait", 7.5, 10.0, category="a", batch=1)
+        assert sp.parent_id == NO_PARENT and sp.t0 == 7.5
+        assert sp.dur_ms == pytest.approx(2500.0)
+        assert sp.attrs == {"batch": 1} and sp.category == "a"
+        assert rec.opened == rec.closed == 2
+        assert rec.hist.rollup(stage="queue_wait").count == 1
+        assert check_span_accounting(rec) == []
+
+
+# ------------------------------------------ device-path spans and accounting
+class TestDevicePathSpans:
+    @pytest.mark.parametrize("index_kind", ["flat", "hnsw"])
+    def test_new_leaves_nest_under_search_and_gate(self, index_kind):
+        clock = SimClock()
+        rec = TraceRecorder(clock)
+        cache = SemanticCache(_policies(), dim=DIM, capacity=64,
+                              clock=clock, seed=0, index_kind=index_kind,
+                              use_device=True, obs=rec)
+        v = _bank(6, 8)
+        for lo in (0, 4):
+            cache.insert_batch(v[lo:lo + 4], ["a"] * 4,
+                               [f"q{i}" for i in range(4)],
+                               [f"r{i}" for i in range(4)])
+            cache.lookup_batch(v[:4], ["a"] * 4)
+        by_id = {sp.span_id: sp for sp in rec.spans}
+
+        def parents(stage):
+            return {by_id[sp.parent_id].stage for sp in rec.spans
+                    if sp.stage == stage}
+        assert parents("device_wait") == {"search"}
+        assert parents("delta_flush") == {"search"}
+        assert parents("clock_charge") == {"search", "gate"}
+        flush = [sp for sp in rec.spans if sp.stage == "delta_flush"]
+        assert len(flush) == 2                  # one per write-then-search
+        assert flush[0].attrs["full"] == 1      # first use: full upload
+        assert flush[0].attrs["bucket"] == 64
+        assert flush[1].attrs["full"] == 0
+        assert flush[1].attrs["rows"] >= 4
+        assert flush[1].attrs["bucket"] >= flush[1].attrs["rows"]
+        charges = [sp.attrs["ms"] for sp in rec.spans
+                   if sp.stage == "clock_charge"]
+        assert sorted(set(charges)) == [cache.insert_ms, cache.search_ms]
+        assert check_span_accounting(rec) == []
+
+    def test_flush_outside_a_search_is_its_own_root(self):
+        from repro.core.hnsw import FlatIndex
+        rec = TraceRecorder(SimClock())
+        idx = FlatIndex(DIM, 32)
+        idx.span = rec.span
+        idx.add_batch(_bank(7, 3), np.zeros(3, np.int32))
+        idx.device_tables()
+        idx.device_tables()                     # nothing changed: no flush
+        (sp,) = rec.spans
+        assert sp.stage == "delta_flush" and sp.parent_id == NO_PARENT
+
+    def test_flush_attrs_are_the_sync_counters(self):
+        """``bucket`` and ``full`` come from the index's own sync
+        counters: their sums are ``rows_synced`` and ``full_uploads``."""
+        from repro.core.hnsw import FlatIndex, _bucket_batch
+        rec = TraceRecorder(SimClock())
+        idx = FlatIndex(DIM, 32)
+        idx.span = rec.span
+        for lo in (0, 3):
+            idx.add_batch(_bank(7, 6)[lo:lo + 3], np.zeros(3, np.int32))
+            idx.device_tables()
+        first, second = [sp.attrs for sp in rec.spans]
+        assert first == {"rows": 3, "bucket": 32, "full": 1}
+        assert second == {"rows": 3, "bucket": _bucket_batch(3), "full": 0}
+        assert first["bucket"] + second["bucket"] == \
+            idx.sync_stats["rows_synced"]
+        assert idx.sync_stats["full_uploads"] == 1
+
+    def test_fault_run_on_the_device_path_closes_accounting(self):
+        sched = FaultSchedule(shard_outages=[(2.0, 6.0, 0)],
+                              store_get_failures=FaultSchedule.op_range(
+                                  5, 2))
+        res = _run(_sim_cfg(True, sched, index_kind="flat",
+                            use_device=True), n=200)
+        rec = res.trace
+        stages = {sp.stage for sp in rec.spans}
+        assert {"device_wait", "delta_flush", "clock_charge"} <= stages
+        assert check_span_accounting(rec) == []
+        assert coverage_fraction(rec) == pytest.approx(1.0)
+
+    def test_replicated_and_migrating_device_caches_close_accounting(self):
+        clock = SimClock()
+        rec = TraceRecorder(clock)
+        cache = ShardedSemanticCache(
+            _policies(), dim=DIM, capacity=256, n_shards=2, clock=clock,
+            seed=0, index_kind="flat", use_device=True,
+            replication={"a": 2}, obs=rec)
+        v = _bank(8, 24)
+        cats = ["a"] * 12 + ["b"] * 12
+        cache.insert_batch(v, cats, [f"q{i}" for i in range(24)],
+                           [f"r{i}" for i in range(24)])
+        cache.lookup_batch(v, cats)
+        cache.migrate_category("b", 1 - cache.shard_of("b"))
+        cache.lookup_batch(v, cats)
+        stages = {sp.stage for sp in rec.spans}
+        assert {"device_wait", "delta_flush", "migration"} <= stages
+        assert check_span_accounting(rec) == []
+
+
+# ------------------------------------------------------- engine queue wait
+class TestEngineQueueWait:
+    def _engine(self, clock, rec, max_batch):
+        from repro.configs import get_config
+        from repro.models import Model
+        from repro.serving.engine import ServingEngine
+        cfg = get_config("llama3_2_3b").reduced(n_layers=2, d_model=64,
+                                                vocab_size=256)
+        cache = SemanticCache(PolicyEngine(paper_policies()), capacity=256,
+                              clock=clock, index_kind="flat", obs=rec)
+        eng = ServingEngine(Model(cfg), None, cache, max_batch=max_batch,
+                            prompt_len=16, max_new_tokens=4, obs=rec)
+        eng._generate = lambda p, t: np.zeros((t.shape[0], 4), np.int32)
+        return eng
+
+    def test_one_queue_wait_per_served_request_from_submit(self):
+        clock = SimClock()
+        rec = TraceRecorder(clock)
+        eng = self._engine(clock, rec, max_batch=2)
+        submitted = {}
+        for k in range(5):
+            rid = eng.submit(f"question number {k} about lists",
+                             "code_generation", np.arange(2, 10))
+            submitted[rid] = clock.now()
+            clock.advance(0.25)
+        served = []
+        while eng.queue:
+            t_step, queued = clock.now(), len(eng.queue)
+            out = eng.step()
+            served += out
+            ended = [sp for sp in rec.spans if sp.stage == "queue_wait"
+                     and sp.t0 + sp.dur_ms / 1e3 == pytest.approx(t_step)]
+            assert len(ended) == len(out) == min(2, queued)
+            clock.advance(0.5)
+        waits = [sp for sp in rec.spans if sp.stage == "queue_wait"]
+        assert len(waits) == len(served) == 5
+        assert sorted(sp.t0 for sp in waits) == sorted(submitted.values())
+        assert all(sp.parent_id == NO_PARENT and sp.dur_ms >= 0
+                   for sp in waits)
+        assert {sp.category for sp in waits} == {"code_generation"}
+        gen = [sp for sp in rec.spans if sp.stage == "model_generate"]
+        assert gen and all(sp.attrs["bucket"] >= sp.attrs["batch"]
+                           and sp.attrs["prompt_tokens"]
+                           == 8 * sp.attrs["batch"] for sp in gen)
+        assert check_span_accounting(rec) == []
+
+    def test_recorder_clock_leaves_latencies_on_the_wall(self):
+        """A SimClock recorder times ``queue_wait`` on its own clock; the
+        responses' latencies and the engine's total stay on the wall
+        clock, as with no recorder (a generate that takes 0.2 s of wall
+        time and none of the SimClock's)."""
+        import time
+
+        def slow(p, t):
+            time.sleep(0.2)
+            return np.zeros((t.shape[0], 4), np.int32)
+        for traced in (False, True):
+            clock = SimClock()
+            rec = TraceRecorder(clock) if traced else None
+            eng = self._engine(clock, rec, max_batch=2)
+            eng._generate = slow
+            eng.submit("an uncached question", "code_generation",
+                       np.arange(2, 10))
+            (r,) = eng.step()
+            assert not r.cached and r.latency_ms >= 200
+            assert eng.stats.total_latency_ms == r.latency_ms
+            if traced:
+                (qw,) = [sp for sp in rec.spans if sp.stage == "queue_wait"]
+                assert qw.dur_ms == 0.0      # the SimClock never moved
+
+    def test_no_recorder_no_queue_wait(self):
+        eng = self._engine(SimClock(), None, max_batch=2)
+        eng.submit("a question", "code_generation", np.arange(2, 10))
+        (r,) = eng.step()
+        assert eng.obs is None and not r.cached

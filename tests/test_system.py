@@ -172,3 +172,54 @@ def test_adaptive_integration_relaxes_threshold(small_model, rng):
                    toks)
     eng.drain()
     assert policies.effective("code_generation").threshold < base_tau
+
+
+def test_engine_stamps_latency_when_the_step_returns(small_model, rng):
+    """A hit found before its step's misses are generated is handed back
+    with them, at step end: every response of a step carries the time
+    to that end, and the engine's latency total is their sum."""
+    import time as _time
+
+    cfg, model, params = small_model
+    policies = PolicyEngine(paper_policies())
+    cache = SemanticCache(policies, capacity=128, clock=SimClock(),
+                          index_kind="flat")
+    eng = ServingEngine(model, params, cache, max_batch=4, prompt_len=16,
+                        max_new_tokens=4)
+    toks = rng.integers(2, cfg.vocab_size, 16)
+    eng.submit("what is a closure", "code_generation", toks)
+    eng.drain()                                  # now cached
+    total0 = eng.stats.total_latency_ms
+
+    def slow_generate(p, t):
+        _time.sleep(0.3)
+        return np.zeros((t.shape[0], 4), np.int32)
+    eng._generate = slow_generate
+    t0 = _time.monotonic()
+    eng.submit("what is a closure", "code_generation", toks)
+    eng.submit("a brand new uncached question", "code_generation", toks)
+    res = eng.step()
+    wall_ms = (_time.monotonic() - t0) * 1e3
+    hit, miss = sorted(res, key=lambda r: not r.cached)
+    assert hit.cached and not miss.cached
+    assert 300 <= hit.latency_ms <= wall_ms
+    assert hit.latency_ms >= miss.latency_ms       # submitted first
+    assert hit.latency_ms - miss.latency_ms < 50
+    assert eng.stats.total_latency_ms - total0 == pytest.approx(
+        hit.latency_ms + miss.latency_ms)
+
+
+def test_generate_program_names_its_phases(small_model):
+    """The generate program's ops carry the ``prefill`` and ``decode``
+    scopes in their metadata: the decode scan's loop and body under
+    ``decode``, the prompt's forward pass under ``prefill``."""
+    import re
+    cfg, model, params = small_model
+    eng = ServingEngine(model, params, None, max_batch=8, prompt_len=16,
+                        max_new_tokens=4)
+    text = eng._generate.lower(
+        params, jnp.zeros((8, 16), jnp.int32)).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any(re.search(r"/decode/while", n) for n in names)
+    assert any(re.search(r"/prefill/", n) for n in names)
+    assert not any("/prefill/" in n and "/decode/" in n for n in names)
