@@ -39,11 +39,13 @@ then exact for that candidate but can differ from an exact-search
 oracle. That needs a near-tie exactly at τ; the τ-boundary property
 test pins the guarantee for separated entries.)
 
-The write path is batched end-to-end: ``insert_batch`` runs one eviction
-scoring pass, one ``store.put_many`` pass and one ``index.add_batch`` pass
-for B entries, whose dirty rows coalesce into a single device delta flush
-on the next search (see core/hnsw.py device residency). ``insert`` is a
-B=1 wrapper — there is only one write path.
+The write path is batched end-to-end: ``insert_batch`` picks each quota or
+capacity victim from the per-category victim index (core/victims.py: the
+oldest entry of each hit bucket is scored, not every live slot), then runs
+one ``store.put_many`` pass and one ``index.add_batch`` pass for B entries,
+whose dirty rows coalesce into a single device delta flush on the next
+search (see core/hnsw.py device residency). ``insert`` is a B=1 wrapper —
+there is only one write path.
 """
 
 from __future__ import annotations
@@ -63,6 +65,7 @@ from repro.core.hnsw import CLS_EXPIRED, CLS_HIT, CLS_MISS, FlatIndex, \
 from repro.core.metrics import MetricsRegistry
 from repro.core.policy import PolicyEngine
 from repro.core.storage import Document, DocumentStore, InMemoryStore
+from repro.core.victims import VictimIndex
 from repro.obs.trace import NULL_SPAN
 
 
@@ -179,6 +182,14 @@ class SemanticCache:
         self.slot_hits = np.zeros(capacity, np.int64)
         self.slot_doc = np.full(capacity, INVALID, np.int64)
         self.slot_valid = np.zeros(capacity, bool)
+        # Live counts per category and the quota/capacity victim index,
+        # kept up to date by ``_write_entries`` and ``_evict_slot``.
+        self._victims = VictimIndex(self)
+        # While a write round picks its victims, every score is taken at
+        # the round's instant and under the policy table resolved once in
+        # it, as one scoring pass would (None: the clock's own time).
+        self._score_at: float | None = None
+        self._score_tables: tuple[np.ndarray, np.ndarray] | None = None
         self._cat_names: dict[int, str] = {}
         self._next_doc_id = doc_id_start
         # Device-search observability (hops, rows gathered) from the last
@@ -195,11 +206,13 @@ class SemanticCache:
 
     # ------------------------------------------------------------------ utils
     def __len__(self) -> int:
-        return int(self.slot_valid.sum())
+        return self._victims.total
 
     def _now(self) -> float:
         """Cache-relative time (see ``_t0``): what slot_inserted stores
         and every TTL/age comparison uses, host and device alike."""
+        if self._score_at is not None:
+            return self._score_at
         return self.clock.now() - self._t0
 
     def _cat_id(self, name: str) -> int:
@@ -220,8 +233,7 @@ class SemanticCache:
             self.obs.event(name, shard=self._obs_shard, **fields)
 
     def category_count(self, name: str) -> int:
-        cid = self.policies.category_id(name)
-        return int((self.slot_valid & (self.slot_category == cid)).sum())
+        return self._victims.count(self.policies.category_id(name))
 
     # -------------------------------------------------------------- Algorithm 1
     def lookup(self, embedding: np.ndarray, category: str) -> CacheResult:
@@ -325,8 +337,9 @@ class SemanticCache:
                 "gathered_bytes": int(np.sum(rows)) * row_bytes,
                 "emb_dtype": self.index.emb_dtype,
                 "reranks": reranks}
-        hit = cls == CLS_HIT
-        np.add.at(self.slot_hits, idxs[hit], 1)   # duplicate slots accumulate
+        hit_slots, n_hits = np.unique(idxs[cls == CLS_HIT],
+                                      return_counts=True)
+        self._write_entries(hit_slots, hits=self.slot_hits[hit_slots] + n_hits)
 
         for pos, i in enumerate(active):
             cat = categories[i]
@@ -380,7 +393,7 @@ class SemanticCache:
                 self._event("store_timeout", category=cat)
                 st.misses += 1
                 st.hits -= 1
-                self.slot_hits[slot] -= 1
+                self._write_entries([slot], hits=self.slot_hits[slot] - 1)
                 results[i] = CacheResult(False, score=score, category=cat,
                                          reason="store_timeout",
                                          latency_ms=self.search_ms)
@@ -389,7 +402,7 @@ class SemanticCache:
                 self._evict_slot(slot, reason="missing_doc")
                 st.misses += 1
                 st.hits -= 1
-                self.slot_hits[slot] -= 1
+                self._write_entries([slot], hits=self.slot_hits[slot] - 1)
                 results[i] = CacheResult(False, score=score, category=cat,
                                          reason="missing_doc",
                                          latency_ms=self.search_ms)
@@ -500,9 +513,10 @@ class SemanticCache:
         temporary data presence), per-category quota, global capacity
         eviction by economic score — but the batch pays batched costs:
 
-        * ONE eviction-scoring pass (§5.4 score = priority × 1/age ×
-          hitRate) over the live slots, updated incrementally as victims
-          fall, instead of a per-item rescore;
+        * each quota or capacity victim (§5.4: the lowest priority ×
+          1/age × hitRate) from the victim index, which scores the oldest
+          entry of each hit bucket at the round's instant, not every live
+          slot;
         * ONE ``store.put_many`` pass for all accepted documents;
         * ONE index write pass (``index.add_batch``) whose touched rows
           coalesce into a single device delta flush on the next search.
@@ -595,26 +609,6 @@ class SemanticCache:
             return slots_out
         admitted = gated
 
-        # Occupancy bookkeeping is one cheap pass; the eviction SCORING
-        # pass (+inf marks non-candidates so victim selection is a masked
-        # argmin, updated as evictions land) is built lazily — a batch
-        # under no quota/capacity pressure never pays it.
-        live_mask = self.slot_valid.copy()
-        cat_snapshot = self.slot_category.copy()
-        cat_counts = {cid: int((live_mask & (cat_snapshot == cid)).sum())
-                      for cid in cids.values()}
-        live_count = int(live_mask.sum())
-        scores: np.ndarray | None = None
-
-        def ensure_scores() -> np.ndarray:
-            nonlocal scores
-            if scores is None:
-                scores = np.full(self.capacity, np.inf, np.float64)
-                live = np.where(live_mask)[0]
-                if live.size:
-                    scores[live] = self._entry_score(live)
-            return scores
-
         # pending: admitted items not yet materialized, as (batch_i, cid,
         # score) — a fresh entry's score comes from the active scorer's
         # ``fresh_score`` (static: pri × 1/age_clamp × 1; cost-aware:
@@ -623,30 +617,13 @@ class SemanticCache:
         # sequential path would.
         pending: list[list] = []
         pending_counts: dict[int, int] = {}
-
-        def evict_existing(slot: int, reason: str) -> int:
-            nonlocal live_count
-            vic_cid = int(cat_snapshot[slot])
-            self._evict_slot(slot, reason=reason)
-            live_mask[slot] = False
-            ensure_scores()[slot] = np.inf
-            cat_counts[vic_cid] = cat_counts.get(vic_cid, 1) - 1
-            live_count -= 1
-            return vic_cid
+        victims = self._victims
 
         def pick_victim(cid: int | None):
             """Lowest-score candidate among live slots (optionally one
             category) and pending batch items. Returns (slot, pending_pos);
             exactly one is valid (INVALID / -1 for the other)."""
-            s = ensure_scores()
-            mask = live_mask if cid is None else \
-                live_mask & (cat_snapshot == cid)
-            cand = np.where(mask)[0]
-            best_slot, best_score = INVALID, np.inf
-            if cand.size:
-                j = int(np.argmin(s[cand]))
-                best_slot = int(cand[j])
-                best_score = float(s[best_slot])
+            best_slot, best_score = self._live_victim(cid)
             best_pos = -1
             for pos, (_, p_cid, p_score) in enumerate(pending):
                 if cid is not None and p_cid != cid:
@@ -655,6 +632,11 @@ class SemanticCache:
                     best_pos, best_score = pos, p_score
                     best_slot = INVALID
             return best_slot, best_pos
+
+        def evict_existing(slot: int, reason: str) -> int:
+            vic_cid = int(self.slot_category[slot])
+            self._evict_slot(slot, reason=reason)
+            return vic_cid
 
         def drop_pending(pos: int, reason_counter: str) -> None:
             """A batch item fell to a later item's pressure before ever
@@ -667,36 +649,49 @@ class SemanticCache:
             setattr(p_st, reason_counter,
                     getattr(p_st, reason_counter) + 1)
 
-        # Span "evict": quota/capacity victim selection for the batch.
-        with self._span("evict", batch=len(admitted)):
-            for i in admitted:
-                c = categories[i]
-                e = eff[c]
-                cid = cids[c]
-                st = self.metrics.cat(c)
-                cat_quota = int(e.quota * self.quota_capacity)
-                n_cat = cat_counts.get(cid, 0) + pending_counts.get(cid, 0)
-                if n_cat >= max(1, cat_quota):
-                    slot, pos = pick_victim(cid)
-                    if slot != INVALID:
-                        evict_existing(slot, "quota")
-                        st.quota_evictions += 1
-                    elif pos >= 0:
-                        # seed attributes quota evictions to the inserting
-                        # category — here victim and inserter share it
-                        drop_pending(pos, "quota_evictions")
-                if live_count + len(pending) >= self.capacity:
-                    slot, pos = pick_victim(None)
-                    if slot != INVALID:
-                        vic_cat = self._cat_names.get(evict_existing(
-                            slot, "capacity"), "?")
-                        self.metrics.cat(vic_cat).capacity_evictions += 1
-                    elif pos >= 0:
-                        drop_pending(pos, "capacity_evictions")
-                pending.append([i, cid,
-                                self._evictor.fresh_score(self, cid,
-                                                          freq.get(i, 1))])
-                pending_counts[cid] = pending_counts.get(cid, 0) + 1
+        # Span "evict": quota/capacity victim selection for the batch. Every
+        # score is taken at the round's ``now``, however long the store's
+        # deletes take; attrs: victims picked, candidates scored, stale
+        # index heads dropped.
+        scored0, stale0, n_victims = victims.scored, victims.stale, 0
+        with self._span("evict", batch=len(admitted)) as sp:
+            self._score_at = now
+            try:
+                for i in admitted:
+                    c = categories[i]
+                    e = eff[c]
+                    cid = cids[c]
+                    st = self.metrics.cat(c)
+                    cat_quota = int(e.quota * self.quota_capacity)
+                    n_cat = victims.count(cid) + pending_counts.get(cid, 0)
+                    if n_cat >= max(1, cat_quota):
+                        slot, pos = pick_victim(cid)
+                        n_victims += slot != INVALID or pos >= 0
+                        if slot != INVALID:
+                            evict_existing(slot, "quota")
+                            st.quota_evictions += 1
+                        elif pos >= 0:
+                            # seed attributes quota evictions to the
+                            # inserting category — here victim and
+                            # inserter share it
+                            drop_pending(pos, "quota_evictions")
+                    if victims.total + len(pending) >= self.capacity:
+                        slot, pos = pick_victim(None)
+                        n_victims += slot != INVALID or pos >= 0
+                        if slot != INVALID:
+                            vic_cat = self._cat_names.get(evict_existing(
+                                slot, "capacity"), "?")
+                            self.metrics.cat(vic_cat).capacity_evictions += 1
+                        elif pos >= 0:
+                            drop_pending(pos, "capacity_evictions")
+                    pending.append([i, cid,
+                                    self._evictor.fresh_score(
+                                        self, cid, freq.get(i, 1))])
+                    pending_counts[cid] = pending_counts.get(cid, 0) + 1
+            finally:
+                self._score_at = self._score_tables = None
+            sp.set(victims=n_victims, scored=victims.scored - scored0,
+                   stale=victims.stale - stale0)
 
         if not pending:
             return slots_out
@@ -731,12 +726,11 @@ class SemanticCache:
             slots = self.index.add_batch(
                 embeddings[order],
                 np.asarray([cid for _, cid, _ in pending], np.int32))
-            for (p_i, _, _), slot, doc in zip(pending, slots, docs):
-                slot = int(slot)
-                self.slot_inserted[slot] = now
-                self.slot_hits[slot] = 0
-                self.slot_doc[slot] = doc.doc_id
-                self.slot_valid[slot] = True
+            slots = np.asarray(slots, np.int64)
+            self.slot_doc[slots] = [doc.doc_id for doc in docs]
+            self.slot_valid[slots] = True
+            self._write_entries(slots, inserted=now, hits=0, new=True)
+            for (p_i, _, _), slot in zip(pending, slots.tolist()):
                 self.metrics.cat(categories[p_i]).inserts += 1
                 slots_out[p_i] = slot
             return slots_out
@@ -777,22 +771,22 @@ class SemanticCache:
                 f"slots (shard_capacity {self.capacity}) — free space "
                 f"on the target or migrate in smaller batches")
         cids = np.asarray([self._cat_id(c) for c in categories], np.int32)
-        slots = self.index.add_batch(embeddings, cids)
+        slots = np.asarray(self.index.add_batch(embeddings, cids), np.int64)
         new_docs, out = [], []
-        for k, slot in enumerate(int(s) for s in slots):
+        for k, slot in enumerate(slots.tolist()):
             d = docs[k]
             doc_id = self._next_doc_id
             self._next_doc_id += self._doc_id_step
             new_docs.append(Document(doc_id, d.request, d.response,
                                      d.created_at, d.category, dict(d.meta),
                                      embedding=d.embedding))
-            # Rows are already dirty from add_batch, so the preserved
-            # timestamp rides the same delta flush as the embedding.
-            self.slot_inserted[slot] = float(inserted[k])
-            self.slot_hits[slot] = int(hits[k])
             self.slot_doc[slot] = doc_id
-            self.slot_valid[slot] = True
             out.append((slot, doc_id))
+        self.slot_valid[slots] = True
+        # Rows are already dirty from add_batch, so the preserved
+        # timestamps ride the same delta flush as the embeddings.
+        self._write_entries(slots, inserted=np.asarray(inserted, np.float64),
+                            hits=np.asarray(hits, np.int64), new=True)
         self.store.put_many(new_docs)
         return out
 
@@ -832,6 +826,8 @@ class SemanticCache:
         numpy indexing — the per-slot Python policy resolution the seed did
         in ``_entry_score``/``sweep_expired`` loops is gone.
         """
+        if self._score_tables is not None:
+            return self._score_tables
         n = (max(self._cat_names) + 1) if self._cat_names else 0
         ttl = np.full(n, np.inf, np.float64)
         pri = np.ones(n, np.float64)
@@ -839,6 +835,8 @@ class SemanticCache:
             eff = self.policies.effective(name)
             ttl[cid] = eff.ttl
             pri[cid] = eff.priority
+        if self._score_at is not None:
+            self._score_tables = ttl, pri
         return ttl, pri
 
     def _entry_score(self, slots: np.ndarray) -> np.ndarray:
@@ -849,13 +847,39 @@ class SemanticCache:
         (core/admission.py). Vectorized over ``slots``."""
         return self._evictor.score(self, slots)
 
+    def _live_victim(self, cid: int | None) -> tuple[int, float]:
+        """(slot, score) of the lowest-scored live entry of category
+        ``cid`` (of every category when None), ties to the lowest slot —
+        the masked argmin over all live slots, from the victim index.
+        Scores come from the active scorer. (INVALID, inf) when none."""
+        return self._victims.pick(
+            cid, lambda slots: self._evictor.score(self, slots))
+
+    def _write_entries(self, slots, *, inserted=None, hits=None,
+                       new: bool = False) -> None:
+        """The one writer of ``slot_inserted`` and ``slot_hits``: writes
+        the given values at ``slots`` and files the live entries there
+        anew in the victim index. ``new``: the entries were just made live
+        (``slot_doc``/``slot_valid`` already set), so they count too."""
+        slots = np.atleast_1d(np.asarray(slots, np.int64))
+        if inserted is not None:
+            self.slot_inserted[slots] = inserted
+            self.index._dirty.update(slots.tolist())
+        if hits is not None:
+            self.slot_hits[slots] = hits
+        live = slots[self.slot_valid[slots]]
+        if new:
+            self._victims.added(self.slot_category[live])
+        self._victims.file(live)
+
     def _evict_slot(self, slot: int, reason: str = "") -> None:
         if not self.slot_valid[slot]:
             return
+        cid = int(self.slot_category[slot])
         if self.obs is not None:
             self._event("eviction", reason=reason,
-                        category=self._cat_names.get(
-                            int(self.slot_category[slot]), "?"))
+                        category=self._cat_names.get(cid, "?"))
+        self._victims.removed(cid)
         self.index.remove(slot)   # also resets the (aliased) category entry
         doc_id = int(self.slot_doc[slot])
         self.store.delete(doc_id)
@@ -941,7 +965,7 @@ class SemanticCache:
         per_entry = rep["in_memory_bytes_per_entry"]
         out: dict[str, dict] = {}
         for cid, name in sorted(self._cat_names.items()):
-            n_cat = int((self.slot_valid & (self.slot_category == cid)).sum())
+            n_cat = self._victims.count(cid)
             quota = self.policies.effective(name).quota
             quota_entries = int(quota * self.quota_capacity)
             out[name] = {

@@ -609,7 +609,8 @@ class CategoryMigration:
             if src_doc not in live_slots:
                 dst._evict_slot(dst_slot, reason="migration_reconcile")
             else:
-                dst.slot_hits[dst_slot] = src.slot_hits[live_slots[src_doc]]
+                dst._write_entries(
+                    dst_slot, hits=src.slot_hits[live_slots[src_doc]])
         self._journal("reconcile")
         self._cp()
         # Flip routing — the point of no return. The category's
@@ -1208,14 +1209,15 @@ class ShardedSemanticCache:
                 return
             # The row is already dirty from the insert's add_batch, so
             # the back-dated timestamp rides the same delta flush.
-            sh.slot_inserted[local] = np.float32(item.t_enq - self._t0)  # mirror-ok
+            sh._write_entries(local,
+                              inserted=np.float32(item.t_enq - self._t0))
             for sj, (oslot, odoc) in sorted(
                     self._rep_registry.get(item.uid, {}).items()):
                 if sj == shard or self._shard_down(sj):
                     continue
                 osh = self.shards[sj]
                 if osh.slot_valid[oslot] and int(osh.slot_doc[oslot]) == odoc:
-                    sh.slot_hits[local] = int(osh.slot_hits[oslot])
+                    sh._write_entries(local, hits=int(osh.slot_hits[oslot]))
                     break
             self._rep_register(item.uid, shard, local, sh.doc_id_of(local))
             return
@@ -1293,7 +1295,7 @@ class ShardedSemanticCache:
             oslot, odoc = ent[sj]
             osh = self.shards[sj]
             if osh.slot_valid[oslot] and int(osh.slot_doc[oslot]) == odoc:
-                osh.slot_hits[oslot] = h
+                osh._write_entries(oslot, hits=h)
             elif not self._shard_down(sj):
                 self.fault_stats["replica_divergence"] += 1
                 self._event("replica_divergence", shard=sj, uid=uid)
@@ -1492,7 +1494,7 @@ class ShardedSemanticCache:
                      responses: Sequence[str],
                      metas: Sequence[dict | None] | None = None) -> list[int]:
         """Partition the write batch per serving shard; each sub-batch
-        pays the shard's single eviction-scoring/store/index pass and
+        pays the shard's victim picks and single store/index pass and
         its touched rows land in that shard's dirty log (one delta flush
         per shard on its next search). Slot ids come back globally
         encoded; INVALID for rejected items, as in the single cache."""

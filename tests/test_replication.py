@@ -26,6 +26,7 @@ Four property families pin the PR's guarantees:
 import numpy as np
 import pytest
 
+from _victim_ref import VictimRecorder, assert_index_agrees
 from repro.core import (FaultInjector, FaultSchedule, InjectedCrash,
                         SemanticCache, ShardedSemanticCache, SimClock)
 from repro.core.policy import CategoryConfig, PolicyEngine
@@ -194,6 +195,75 @@ def test_replicas_converge_after_write_catchup():
     for _ in range(4):
         assert all(r.hit for r in cache.lookup_batch(bank[:8], ["a"] * 8))
     assert cache.fault_stats["replica_divergence"] == 0
+
+
+def test_replica_writers_keep_the_victim_index():
+    """The replica hit echo and the write-behind catch-up (back-dated
+    timestamp, sibling's hit count) file their writes in the victim
+    index: every shard's index agrees with the brute force after each,
+    and under quota pressure both replicas pick the brute force's
+    victims and stay bit-identical."""
+    clk = SimClock()
+    inj = FaultInjector(FaultSchedule(shard_outages=[(1.0, 5.0, 1)]), clk)
+    cache = _sharded(faults=inj, clock=clk, replication={"a": 2})
+    bank = _bank("a", 160)
+    cache.insert_batch(bank[:4], ["a"] * 4, [f"q{i}" for i in range(4)],
+                       [f"r{i}" for i in range(4)])
+    for _ in range(2):                  # served by turns, echoed to both
+        assert all(r.hit for r in cache.lookup_batch(bank[:4], ["a"] * 4))
+    for sh in cache.shards:
+        assert_index_agrees(sh)
+    clk.advance(2.0)                    # replica 1 down: writes queue
+    cache.insert_batch(bank[4:8], ["a"] * 4, [f"q{i}" for i in range(4, 8)],
+                       [f"r{i}" for i in range(4, 8)])
+    clk.advance(0.5)
+    assert all(r.hit for r in cache.lookup_batch(bank[4:8], ["a"] * 4))
+    clk.advance(10.0)                   # recovery; this op replays
+    cache.lookup_batch(bank[:1], ["a"])
+    assert cache.wb_pending == 0
+    assert cache.fault_stats["wb_replayed"] == 4
+    for sh in cache.shards:
+        assert_index_agrees(sh)
+    assert _cat_state(cache.shards[0], "a") == \
+        _cat_state(cache.shards[1], "a")
+    recs = [VictimRecorder(sh) for sh in cache.shards]
+    for lo in range(8, 152, 16):
+        clk.advance(0.25)
+        res = cache.lookup_batch(bank[lo - 8:lo], ["a"] * 8)
+        assert all(r.hit for r in res)
+        cache.insert_batch(bank[lo:lo + 16], ["a"] * 16,
+                           [f"q{i}" for i in range(lo, lo + 16)],
+                           [f"r{i}" for i in range(lo, lo + 16)])
+    for rec in recs:
+        assert len(rec.got) >= 40 and rec.got == rec.want
+    for sh in cache.shards:
+        assert_index_agrees(sh)
+    assert _cat_state(cache.shards[0], "a") == \
+        _cat_state(cache.shards[1], "a")
+    assert cache.fault_stats["replica_divergence"] == 0
+
+
+def test_write_behind_backdating_alone_keeps_the_victim_index():
+    """A catch-up whose live sibling lost its copy back-dates the entry
+    with no hit copy after it: the back-dated entries are filed anew."""
+    clk = SimClock()
+    inj = FaultInjector(FaultSchedule(shard_outages=[(1.0, 5.0, 1)]), clk)
+    cache = _sharded(faults=inj, clock=clk, replication={"a": 2})
+    bank = _bank("a")
+    clk.advance(2.0)                    # replica 1 down: writes queue
+    cache.insert_batch(bank[:4], ["a"] * 4, [f"q{i}" for i in range(4)],
+                       [f"r{i}" for i in range(4)])
+    live = cache.shards[0]
+    for s in live.category_slots("a"):
+        live._evict_slot(int(s), reason="ttl")
+    clk.advance(10.0)                   # recovery; this op replays
+    cache.lookup_batch(_bank("b")[:1], ["b"])
+    assert cache.wb_pending == 0
+    back = cache.shards[1]
+    slots = back.category_slots("a")
+    assert len(slots) == 4
+    assert (back.slot_inserted[slots] < back._now() - 9.0).all()
+    assert_index_agrees(back)
 
 
 # -------------------------------------------------------- failover + routing

@@ -14,6 +14,7 @@ reproducible.
 import numpy as np
 import pytest
 
+from _victim_ref import VictimRecorder, assert_index_agrees
 from repro.core import SemanticCache, SimClock
 from repro.core.economics import ResidencyModel
 from repro.core.hnsw import INVALID, quantize_rows
@@ -463,6 +464,37 @@ def test_migration_reconciles_source_evictions_and_hits():
     # drain-time hits carried over to the target's eviction scoring
     hit_slots = cache.shards[dst].category_slots("a")
     assert cache.shards[dst].slot_hits[hit_slots].sum() >= 5
+
+
+def test_migration_reconcile_keeps_the_victim_index():
+    """Hits the source served during the drain reach the target's victim
+    index at the reconcile: both shards' indexes agree with the brute
+    force, and the target's quota victims are the brute force's."""
+    cache = _migration_cache()
+    banks = _banks(160)
+    cache.insert_batch(banks["a"][:40], ["a"] * 40,
+                       [f"q{i}" for i in range(40)],
+                       [f"r{i}" for i in range(40)])
+    src, dst = cache.shard_of("a"), 1 - cache.shard_of("a")
+    mig = cache.migrate_category("a", dst, batch_size=40, stepwise=True)
+    assert mig.step() == 40
+    cache.clock.advance(1.0)
+    cache.lookup_batch(banks["a"][5:25], ["a"] * 20)
+    cache.lookup_batch(banks["a"][5:15], ["a"] * 10)
+    mig.cutover()
+    target = cache.shards[dst]
+    assert target.slot_hits[target.category_slots("a")].max() == 2
+    for sh in cache.shards:
+        assert_index_agrees(sh)
+    rec = VictimRecorder(target)
+    cache.clock.advance(1.0)
+    for lo in range(40, 160, 20):
+        cache.insert_batch(banks["a"][lo:lo + 20], ["a"] * 20,
+                           [f"q{i}" for i in range(lo, lo + 20)],
+                           [f"r{i}" for i in range(lo, lo + 20)])
+        cache.clock.advance(0.5)
+    assert len(rec.got) >= 20 and rec.got == rec.want
+    assert_index_agrees(target)
 
 
 def test_rebalance_follows_quota_reassignment():
