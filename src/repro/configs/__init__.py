@@ -24,6 +24,7 @@ ARCH_IDS = [
     "jamba_v0_1_52b",
     "llava_next_mistral_7b",
     "falcon_mamba_7b",
+    "granite_4_0_h_small",
 ]
 
 # Canonical hyphenated ids from the assignment → module names.
@@ -38,6 +39,7 @@ ALIASES = {
     "jamba-v0.1-52b": "jamba_v0_1_52b",
     "llava-next-mistral-7b": "llava_next_mistral_7b",
     "falcon-mamba-7b": "falcon_mamba_7b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 

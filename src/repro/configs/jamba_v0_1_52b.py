@@ -4,7 +4,10 @@
 [arXiv:2403.19887; hf]. Each 8-layer block: attention at index 4, Mamba
 elsewhere; MoE FFN on odd layers (16 of 32), dense d_ff=14336 on even.
 Runs long_500k (sub-quadratic: 4 of 32 layers are attention; those use a
-4096-token sliding window at 500 k with KV-sequence sharding).
+4096-token sliding window at 500 k with KV-sequence sharding). The
+attention layers use no positional encoding (NoPE; the Mamba layers carry
+position) [arXiv:2403.19887 §2; hf ``JambaAttention`` has no rotary
+embedding].
 """
 
 from repro.models.config import ArchConfig
@@ -24,7 +27,7 @@ CONFIG = ArchConfig(
     moe_every=2,
     moe_offset=1,
     vocab_size=65536,
-    rope_theta=10000.0,
+    rope_theta=None,
     hybrid_period=8,
     hybrid_attn_index=4,
     ssm_d_state=16,

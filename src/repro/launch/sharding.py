@@ -115,8 +115,8 @@ def param_plan(cfg: ArchConfig, dist: Dist, *, training: bool) -> ShardingPlan:
             return wrap(model, None)
         if leaf == "w_dt":
             return wrap(None, model)
-        if leaf == "A_log":
-            return wrap(model, None)
+        if leaf == "A_log":                    # mamba (di, N); mamba2 (H,)
+            return wrap(model, None) if len(base) == 2 else wrap(None)
         if leaf == "w_out":                                    # (di, d)
             return wrap(model, fsdp)
         if leaf == "proj":                                     # whisper frontend

@@ -6,7 +6,8 @@ through ``repro.kernels.ops.flash_attention`` / ``decode_attention`` instead.
 
 Supports: grouped KV heads, sliding-window + causal masks with absolute
 positions (``kv_offset`` for chunked prefill), attention logit softcap
-(gemma2), ragged decode lengths.
+(gemma2), a configured score scale (granite's ``attention_multiplier``;
+default head_dim ** -0.5), ragged decode lengths.
 """
 
 from __future__ import annotations
@@ -34,7 +35,8 @@ def qkv_project(x: jax.Array, p: dict, positions: jax.Array,
 def attend_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                    causal: bool = True, window: int | None = None,
                    softcap: float | None = None, kv_offset: int | jax.Array = 0,
-                   kv_chunk: int = 1024) -> jax.Array:
+                   kv_chunk: int = 1024, scale: float | None = None
+                   ) -> jax.Array:
     """q (B, Sq, H, dh); k/v (B, Skv, Hkv, dh) → (B, Sq, H, dh).
 
     Online-softmax over KV chunks (flash structure in jnp): logits exist
@@ -47,7 +49,8 @@ def attend_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, Sq, H, dh = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     g = H // Hkv
-    qg = q.reshape(B, Sq, Hkv, g, dh).astype(jnp.float32) * (dh ** -0.5)
+    qg = q.reshape(B, Sq, Hkv, g, dh).astype(jnp.float32) * (
+        dh ** -0.5 if scale is None else scale)
     qpos = jnp.arange(Sq)[:, None] + kv_offset                 # (Sq, 1)
 
     kc = min(kv_chunk, Skv)
@@ -118,7 +121,8 @@ def attend_prefill_dynwin(q, k, v, *, window: jax.Array,
 
 def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                   kv_len: jax.Array, *, window: int | jax.Array | None = None,
-                  softcap: float | None = None) -> jax.Array:
+                  softcap: float | None = None,
+                  scale: float | None = None) -> jax.Array:
     """One-token decode. q (B, H, dh); caches (B, S, Hkv, dh); kv_len (B,).
 
     The new token sits at absolute position kv_len − 1 (already appended).
@@ -137,7 +141,8 @@ def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     g = H // Hkv
     qg = q.reshape(B, Hkv, g, dh)
     logits = jnp.einsum("bhgd,bkhd->bhgk", qg.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) * (dh ** -0.5)
+                        k_cache.astype(jnp.float32)) * (
+        dh ** -0.5 if scale is None else scale)
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     kpos = jnp.arange(S)[None, :]

@@ -24,7 +24,8 @@ class ArchConfig:
     head_dim: int | None = None       # default d_model // n_heads
 
     # --- attention flavor ---------------------------------------------------
-    rope_theta: float = 10000.0
+    rope_theta: float | None = 10000.0       # None: NoPE (no positional signal)
+    attn_scale: float | None = None          # score scale; None = head_dim ** -0.5
     sliding_window: int | None = None        # window for local layers
     local_global_alternating: bool = False   # gemma2: even layers local
     attn_softcap: float | None = None        # gemma2: 50.0
@@ -38,11 +39,27 @@ class ArchConfig:
     moe_offset: int = 0
     capacity_factor: float = 1.5
     router_aux_weight: float = 0.01
+    d_ff_shared: int = 0              # SwiGLU shared expert beside the routed ones
+    # Expert share held here: ids [experts_first, experts_first + experts_held)
+    # of the router's n_experts (0 = all). The router still routes over all
+    # n_experts; absent experts add nothing.
+    experts_held: int = 0
+    experts_first: int = 0
 
-    # --- SSM (Mamba1) ----------------------------------------------------------
+    # --- SSM: Mamba-1 (per-channel state) or Mamba-2 (SSD, per-head state) ----
+    ssm_kind: str = "mamba1"          # mamba1 | mamba2
     ssm_d_state: int = 16
     ssm_d_conv: int = 4
     ssm_expand: int = 2
+    ssm_head_dim: int = 64            # mamba2: channels per head
+    ssm_n_groups: int = 1             # mamba2: B/C groups shared by the heads
+    ssm_chunk: int = 256              # mamba2: SSD chunk length (prefill/train)
+
+    # --- residual stream scalings (granite) ---------------------------------------
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on both residual branches
+    logits_scaling: float = 1.0       # logits are divided by this
+    tie_embeddings: bool = False      # LM head is the embedding table
 
     # --- hybrid (jamba): within each block of ``hybrid_period`` layers,
     #     layer index ``hybrid_attn_index`` is attention, the rest Mamba.
@@ -79,6 +96,10 @@ class ArchConfig:
             raise ValueError(f"{self.name}: moe family needs experts/top_k")
         if self.family in ("dense", "moe", "vlm") and self.n_heads % max(1, self.n_kv_heads):
             raise ValueError(f"{self.name}: n_heads must divide by n_kv_heads")
+        if self.experts_first + self.n_experts_held > self.n_experts:
+            raise ValueError(f"{self.name}: held experts exceed n_experts")
+        if self.ssm_kind == "mamba2" and self.ssm_d_inner % self.ssm_head_dim:
+            raise ValueError(f"{self.name}: d_inner must divide by ssm_head_dim")
 
     # -- derived ------------------------------------------------------------------
     @property
@@ -92,6 +113,19 @@ class ArchConfig:
     @property
     def ssm_d_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def ssm_n_heads(self) -> int:
+        return self.ssm_d_inner // self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Mamba-2's conv runs over x, B and C together."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     def layer_kinds(self) -> list[str]:
         """Static per-layer structure: 'attn' or 'mamba'."""
@@ -120,12 +154,16 @@ class ArchConfig:
         """Analytic parameter count (drives 6·N·D MODEL_FLOPS)."""
         d, ff, V = self.d_model, self.d_ff, self.vocab_size
         n = 0
-        n += V * d * 2                                        # embed + head
+        n += V * d * (1 if self.tie_embeddings else 2)        # embed (+ head)
         kinds = self.layer_kinds()
         mlps = self.mlp_kinds()
         for i in range(self.n_layers):
             if kinds[i] == "attn":
                 n += d * self.attn_dim + 2 * d * self.kv_dim + self.attn_dim * d
+            elif self.ssm_kind == "mamba2":
+                di, nh, cd = self.ssm_d_inner, self.ssm_n_heads, self.ssm_conv_dim
+                n += d * (di + cd + nh) + (self.ssm_d_conv + 1) * cd \
+                     + 3 * nh + di + di * d    # in, conv+bias, dt_bias/A/D, norm, out
             else:
                 di = self.ssm_d_inner
                 ns = self.ssm_d_state
@@ -135,6 +173,7 @@ class ArchConfig:
                 n += 3 * d * ff
             elif mlps[i] == "moe":
                 n += 3 * d * self.d_ff_expert * self.n_experts + d * self.n_experts
+                n += 3 * d * self.d_ff_shared
             n += 2 * d                                        # norms
         if self.family == "encdec":
             for _ in range(self.enc_layers):
@@ -171,6 +210,8 @@ class ArchConfig:
             enc_dim=48 if self.family == "encdec" else 0,
             n_patches=8 if self.family == "vlm" else 0,
             ssm_d_state=8, ssm_expand=2,
+            d_ff_shared=64 if self.d_ff_shared else 0, ssm_chunk=16,
+            experts_held=0, experts_first=0,
             grad_accum=1, prefill_chunk=None, loss_chunk=64,
         )
         kw.update(overrides)

@@ -124,14 +124,20 @@ def init_mamba(key, cfg) -> dict:
 
 
 def init_moe(key, cfg) -> dict:
+    """Router over all n_experts; weights of the held experts only; the
+    shared expert where ``d_ff_shared`` is set."""
     dt = dtype_of(cfg)
     d, ffe = cfg.d_model, cfg.d_ff_expert
-    E = cfg.n_experts
+    E, held = cfg.n_experts, cfg.n_experts_held
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s_in, s_out = d ** -0.5, ffe ** -0.5
-    return {
+    p = {
         "router": _normal(k1, (d, E), jnp.float32, s_in),
-        "w_gate": _normal(k2, (E, d, ffe), dt, s_in),
-        "w_up": _normal(k3, (E, d, ffe), dt, s_in),
-        "w_down": _normal(k4, (E, ffe, d), dt, s_out),
+        "w_gate": _normal(k2, (held, d, ffe), dt, s_in),
+        "w_up": _normal(k3, (held, d, ffe), dt, s_in),
+        "w_down": _normal(k4, (held, ffe, d), dt, s_out),
     }
+    if cfg.d_ff_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d,
+                               cfg.d_ff_shared, dt)
+    return p

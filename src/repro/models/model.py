@@ -57,6 +57,8 @@ class Model:
             "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
             "stack": tf.init_stack(keys[2], cfg),
         }
+        if cfg.tie_embeddings:
+            del params["head"]
         if cfg.family == "encdec":
             enc_cfg = self._enc_cfg()
             params["enc"] = {
@@ -83,7 +85,21 @@ class Model:
         x = jnp.take(params["embed"], tokens, axis=0)
         if self.cfg.final_softcap is not None:   # gemma-style embed scaling
             x = x * jnp.asarray(self.cfg.d_model ** 0.5, x.dtype)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.cfg.embedding_multiplier, x.dtype)
         return x
+
+    def _head(self, params):
+        """The LM head's (Vp, d) table: the embedding's where tied."""
+        return params["embed"] if self.cfg.tie_embeddings else params["head"]
+
+    def _scale_logits(self, logits):
+        cfg = self.cfg
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        if cfg.final_softcap is not None:
+            logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
+        return logits
 
     # ------------------------------------------------------------- encoder
     def _encode(self, params, audio):
@@ -152,16 +168,15 @@ class Model:
         if S % chunk:
             chunk = S
         nc = S // chunk
-        head = params["head"]
+        head = self._head(params)
         vp = head.shape[0]
         vmask = (jnp.arange(vp) < cfg.vocab_size)
 
         def body(carry, inp):
             xc, lc = inp                                  # (B,c,d), (B,c)
-            logits = jnp.einsum("bcd,vd->bcv", xc.astype(jnp.float32),
-                                head.astype(jnp.float32))
-            if cfg.final_softcap is not None:
-                logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
+            logits = self._scale_logits(jnp.einsum(
+                "bcd,vd->bcv", xc.astype(jnp.float32),
+                head.astype(jnp.float32)))
             logits = jnp.where(vmask[None, None], logits, -1e30)
             logp = jax.nn.log_softmax(logits, axis=-1)
             take = jnp.take_along_axis(
@@ -181,11 +196,11 @@ class Model:
 
     def _logits_last(self, params, x_last):
         cfg = self.cfg
-        logits = jnp.einsum("bd,vd->bv", x_last.astype(jnp.float32),
-                            params["head"].astype(jnp.float32))
-        if cfg.final_softcap is not None:
-            logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
-        vp = params["head"].shape[0]
+        head = self._head(params)
+        logits = self._scale_logits(jnp.einsum(
+            "bd,vd->bv", x_last.astype(jnp.float32),
+            head.astype(jnp.float32)))
+        vp = head.shape[0]
         return jnp.where(jnp.arange(vp)[None, :] < cfg.vocab_size,
                          logits, -1e30)
 

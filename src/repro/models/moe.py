@@ -2,9 +2,12 @@
 
 Two implementations sharing one routing function:
 
-``moe_ffn_dense``
-    Reference one-hot dispatch (einsum). Exact, O(T·E·C) memory —
-    used by smoke tests and as the oracle for the EP path.
+``moe_ffn_dense_exact``
+    Every held expert on every token, then the routed combine. Exact;
+    the one-chip path and the oracle for the EP path. A layer may hold a
+    share of the experts (``cfg.experts_held`` from ``cfg.experts_first``):
+    it routes over all ``n_experts`` and returns only its own experts'
+    part, as one expert-parallel shard would.
 
 ``moe_ffn_ep``
     Production path under ``shard_map``: experts are owned by ``data``
@@ -35,6 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.context import Dist
+from repro.models.layers import swiglu
 
 
 def _ragged_dot(lhs, rhs, group_sizes):
@@ -69,15 +73,19 @@ def route(x: jax.Array, router_w: jax.Array, cfg, n_expert_pad: int
 
 
 def moe_ffn_dense_exact(x: jax.Array, p: dict, cfg) -> tuple[jax.Array, jax.Array]:
-    """Exact reference: every expert applied to every token, then weighted
-    combine. O(T·E) compute — only for tiny test configs."""
+    """Exact: every held expert applied to every token, then the weighted
+    combine of the routes that land on held experts (routes to absent
+    experts add nothing). O(T·E_held) compute."""
     ids, weights, aux = route(x, p["router"], cfg, cfg.n_experts)
+    if cfg.experts_first:
+        ids = ids - cfg.experts_first
     xf = x.astype(jnp.float32)
     g = jnp.einsum("td,edf->etf", xf, p["w_gate"].astype(jnp.float32))
     u = jnp.einsum("td,edf->etf", xf, p["w_up"].astype(jnp.float32))
     h = jax.nn.silu(g) * u
     y_all = jnp.einsum("etf,efd->etd", h, p["w_down"].astype(jnp.float32))
-    onehot = jax.nn.one_hot(ids, cfg.n_experts, dtype=jnp.float32)   # (T,k,E)
+    onehot = jax.nn.one_hot(ids, cfg.n_experts_held,
+                            dtype=jnp.float32)                       # (T,k,E)
     combine = (weights[..., None] * onehot).sum(axis=1)              # (T,E)
     y = jnp.einsum("etd,te->td", y_all, combine)
     return y.astype(x.dtype), aux
@@ -181,6 +189,9 @@ def moe_ffn_ep(x: jax.Array, p: dict, cfg, dist: Dist,
     expert FFNs for its slice — no model-axis psum, 1/n_model the
     per-device routing bytes, MXU-aligned GEMMs (§Perf A iteration 3).
     """
+    if cfg.n_experts_held != cfg.n_experts:
+        raise ValueError(f"{cfg.name}: the EP path shards all n_experts "
+                         "itself; it takes no held share")
     n_data = dist.n_data
     e_pad = padded_experts(cfg.n_experts, n_data)
     ids, weights, aux = route(x, p["router"], cfg, e_pad)
@@ -229,7 +240,8 @@ def moe_ffn_ep(x: jax.Array, p: dict, cfg, dist: Dist,
 
 def moe_apply(x: jax.Array, p: dict, cfg, dist: Dist | None
               ) -> tuple[jax.Array, jax.Array]:
-    """Dispatch: EP under a real mesh, exact dense reference otherwise.
+    """Dispatch: EP under a real mesh, exact dense reference otherwise;
+    plus the shared expert where the layer has one (``p["shared"]``).
     x may be (B, S, d) or (T, d); returns same leading shape."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
@@ -247,4 +259,7 @@ def moe_apply(x: jax.Array, p: dict, cfg, dist: Dist | None
         # computes its expert shard for all tokens; GSPMD's einsum
         # partitioning handles it without routing buffers.
         y, aux = moe_ffn_dense_exact(x2, p, cfg)
+    if "shared" in p:
+        s = p["shared"]
+        y = y + swiglu(x2, s["w_gate"], s["w_up"], s["w_down"]).astype(y.dtype)
     return y.reshape(shape), aux

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.distributed.context import Dist
 from repro.models import attention as attn
 from repro.models import mamba as mam
+from repro.models import mamba2 as mam2
 from repro.models import moe as moe_mod
 from repro.models.layers import (dtype_of, init_attention, init_mamba,
                                  init_mlp, init_moe, rms_norm, swiglu)
@@ -76,6 +77,8 @@ def init_sublayer(key, cfg, spec: SubLayerSpec) -> dict:
     p: dict = {"ln_mix": jnp.zeros((d,), jnp.float32)}
     if spec.kind == "attn":
         p["mix"] = init_attention(keys[0], cfg)
+    elif cfg.ssm_kind == "mamba2":
+        p["mix"] = mam2.init_mamba2(keys[0], cfg)
     else:
         p["mix"] = init_mamba(keys[0], cfg)
     if spec.cross:
@@ -121,6 +124,8 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None) -> dict:
                                     cfg.head_dim), dt),
                     "v": jnp.zeros((batch, max_len, cfg.n_kv_heads,
                                     cfg.head_dim), dt)}
+        if cfg.ssm_kind == "mamba2":
+            return mam2.init_mamba2_state(cfg, batch, dt)
         return mam.init_mamba_state(cfg, batch, dt)
 
     def stack(tree):
@@ -135,12 +140,13 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None) -> dict:
 # ---------------------------------------------------------------------------
 
 def _q_chunked_attend(q, k, v, *, causal, window, softcap, kv_offset,
-                      q_chunk: int):
+                      q_chunk: int, scale=None):
     """Scan over query chunks so (Sq×Skv) logits never materialize."""
     B, Sq, H, dh = q.shape
     if Sq <= q_chunk:
         return attn.attend_prefill(q, k, v, causal=causal, window=window,
-                                   softcap=softcap, kv_offset=kv_offset)
+                                   softcap=softcap, kv_offset=kv_offset,
+                                   scale=scale)
     if Sq % q_chunk:
         # largest divisor of Sq ≤ q_chunk (whisper's 1500-frame encoder)
         q_chunk = next(c for c in range(q_chunk, 0, -1) if Sq % c == 0)
@@ -151,7 +157,8 @@ def _q_chunked_attend(q, k, v, *, causal, window, softcap, kv_offset,
         qc, i = inp
         out = attn.attend_prefill(qc, k, v, causal=causal, window=window,
                                   softcap=softcap,
-                                  kv_offset=kv_offset + i * q_chunk)
+                                  kv_offset=kv_offset + i * q_chunk,
+                                  scale=scale)
         return None, out
 
     _, outs = jax.lax.scan(body, None,
@@ -174,10 +181,11 @@ def _seq_shard(arr, dist, cfg):
 def attn_sublayer(x, sp, cfg, spec: SubLayerSpec, *, mode: str,
                   positions, cache=None, kv_len=None, kv_offset: int = 0,
                   q_chunk: int = 256, dist=None):
-    """Returns (out (same shape as x), new_cache)."""
+    """Returns (the mixer's output, to be added to x; new_cache)."""
     h = rms_norm(x, sp["ln_mix"], cfg.norm_eps)
     theta = cfg.rope_theta if cfg.family != "encdec" else None
     window = spec.window
+    scale = cfg.attn_scale
 
     if mode == "decode":
         # x (B, 1, d); cache (B, S, Hkv, dh); write at kv_len, read ≤ kv_len.
@@ -187,9 +195,10 @@ def attn_sublayer(x, sp, cfg, spec: SubLayerSpec, *, mode: str,
         new_k = cache["k"].at[bidx, kv_len].set(k[:, 0].astype(cache["k"].dtype))
         new_v = cache["v"].at[bidx, kv_len].set(v[:, 0].astype(cache["v"].dtype))
         out = attn.attend_decode(q[:, 0], new_k, new_v, kv_len + 1,
-                                 window=window, softcap=cfg.attn_softcap)
+                                 window=window, softcap=cfg.attn_softcap,
+                                 scale=scale)
         out = attn.out_project(out, sp["mix"])[:, None, :]
-        return x + out.astype(x.dtype), {"k": new_k, "v": new_v}
+        return out, {"k": new_k, "v": new_v}
 
     q, k, v = attn.qkv_project(h, sp["mix"], positions, theta)
     new_cache = cache
@@ -207,17 +216,16 @@ def attn_sublayer(x, sp, cfg, spec: SubLayerSpec, *, mode: str,
             v_att = jax.lax.slice_in_dim(new_v, 0, hist, axis=1).astype(q.dtype)
             out = _q_chunked_attend(q, k_att, v_att, causal=spec.causal,
                                     window=window, softcap=cfg.attn_softcap,
-                                    kv_offset=kv_offset, q_chunk=q_chunk)
-            out = attn.out_project(out, sp["mix"])
-            return x + out.astype(x.dtype), new_cache
+                                    kv_offset=kv_offset, q_chunk=q_chunk,
+                                    scale=scale)
+            return attn.out_project(out, sp["mix"]), new_cache
 
     q = _seq_shard(q, dist, cfg)
     out = _q_chunked_attend(q, k, v, causal=spec.causal, window=window,
                             softcap=cfg.attn_softcap, kv_offset=0,
-                            q_chunk=q_chunk)
+                            q_chunk=q_chunk, scale=scale)
     out = _seq_shard(out, dist, cfg)
-    out = attn.out_project(out, sp["mix"])
-    return x + out.astype(x.dtype), new_cache
+    return attn.out_project(out, sp["mix"]), new_cache
 
 
 def cross_sublayer(x, sp, cfg, enc_kv):
@@ -239,30 +247,38 @@ def sublayer_apply(x, sp, cfg, spec: SubLayerSpec, dist: Dist | None, *,
                    enc_kv=None):
     """Returns (x, new_cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
+
+    def residual(x, out):
+        if cfg.residual_multiplier != 1.0:
+            out = out * cfg.residual_multiplier
+        return x + out.astype(x.dtype)
+
     if spec.kind == "attn":
-        x, new_cache = attn_sublayer(x, sp, cfg, spec, mode=mode,
-                                     positions=positions, cache=cache,
-                                     kv_len=kv_len, kv_offset=kv_offset,
-                                     dist=dist)
+        out, new_cache = attn_sublayer(x, sp, cfg, spec, mode=mode,
+                                       positions=positions, cache=cache,
+                                       kv_len=kv_len, kv_offset=kv_offset,
+                                       dist=dist)
     else:
         h = rms_norm(x, sp["ln_mix"], cfg.norm_eps)
-        if mode == "train":
-            out, new_cache = mam.mamba_block(h, sp["mix"], cfg, state=None)
+        state = None if mode == "train" else cache
+        if cfg.ssm_kind == "mamba2":
+            out, new_cache = mam2.mamba2_block(h, sp["mix"], cfg, state=state,
+                                               decode=mode == "decode")
         else:
-            out, new_cache = mam.mamba_block(h, sp["mix"], cfg, state=cache)
-        x = x + out.astype(x.dtype)
+            out, new_cache = mam.mamba_block(h, sp["mix"], cfg, state=state)
+    x = residual(x, out)
 
     if spec.cross and enc_kv is not None:
         x = cross_sublayer(x, sp, cfg, enc_kv)
 
     if spec.mlp == "dense":
         h = rms_norm(x, sp["ln_mlp"], cfg.norm_eps)
-        x = x + swiglu(h, sp["mlp"]["w_gate"], sp["mlp"]["w_up"],
-                       sp["mlp"]["w_down"]).astype(x.dtype)
+        x = residual(x, swiglu(h, sp["mlp"]["w_gate"], sp["mlp"]["w_up"],
+                               sp["mlp"]["w_down"]))
     elif spec.mlp == "moe":
         h = rms_norm(x, sp["ln_mlp"], cfg.norm_eps)
         y, aux = moe_mod.moe_apply(h, sp["mlp"], cfg, dist)
-        x = x + y.astype(x.dtype)
+        x = residual(x, y)
     return x, new_cache, aux
 
 

@@ -127,7 +127,8 @@ class ServingEngine:
         self._next_id = 0
 
         cfg = model.cfg
-        max_len = prompt_len + max_new_tokens
+        max_len = self._max_len = prompt_len + max_new_tokens
+        self._state_mb_of: dict[int, float] = {}
 
         # The named scopes label each phase's device ops in a profile
         # (metadata only: the program is the same without them).
@@ -153,6 +154,17 @@ class ServingEngine:
             return jnp.concatenate([tok0[None], toks], axis=0).T  # (B, new)
 
         self._generate = jax.jit(generate)
+
+    def _state_mb(self, bucket: int) -> float:
+        """MiB of recurrent and KV state a generate of ``bucket`` rows
+        carries, from the cache pytree's shapes (once per bucket)."""
+        if bucket not in self._state_mb_of:
+            shapes = jax.eval_shape(
+                lambda: self.model.init_cache(bucket, self._max_len))
+            self._state_mb_of[bucket] = sum(
+                s.size * s.dtype.itemsize
+                for s in jax.tree.leaves(shapes)) / 2**20
+        return self._state_mb_of[bucket]
 
     def _span(self, stage: str, **attrs):
         if self.obs is None:
@@ -228,9 +240,12 @@ class ServingEngine:
                 toks[j, :len(p)] = p
             prompt_tokens = sum(min(len(batch[i].prompt_tokens),
                                     self.prompt_len) for i in misses)
+            attrs = {}
+            if self.obs is not None:
+                attrs["state_mb"] = self._state_mb(toks.shape[0])
             with self._span("model_generate", batch=len(misses),
                             bucket=toks.shape[0],
-                            prompt_tokens=prompt_tokens):
+                            prompt_tokens=prompt_tokens, **attrs):
                 out = np.asarray(
                     self._generate(self.params, jnp.asarray(toks)))
             out = out[:len(misses)]

@@ -5,8 +5,9 @@ never asks the TPU compiler anything: a block shape or a DMA the chip's
 compiler refuses passes every interpret-mode test. These tests compile —
 for a described (not attached) v5e chip, with the compiler libtpu ships —
 each cache kernel of the served path at the paper's scale (N = 2^20 rows,
-d = 384, fp32 and int8 residency) and granite-moe-3b-a800m's generate
-program at published width and depth. Nothing runs: they pin what the
+d = 384, fp32 and int8 residency), granite-moe-3b-a800m's generate
+program at published width and depth, and granite-4.0-h-small's at
+published width, cut to one chip's share. Nothing runs: they pin what the
 compiler accepts, not results.
 
 The topology is described inside a module-scoped fixture, never at import:
@@ -108,4 +109,28 @@ def test_granite_moe_generate_compiles_for_v5e(one_chip):
     compiled = engine._generate.lower(params, toks).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 6 * 10**9      # 6.77 GB of bf16
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_granite_4h_generate_compiles_for_v5e(one_chip):
+    """The same for granite-4.0-h-small cut to one chip's share (one
+    10-layer period: 9 Mamba-2 layers with their fp32 state, one NoPE
+    attention layer; 18 of 72 experts and the shared expert on every
+    layer; the tied 100,352-row vocabulary)."""
+    from dataclasses import replace
+
+    from repro.configs import get_config
+    from repro.models.model import Model
+    from repro.serving.engine import ServingEngine
+    cfg = replace(get_config("granite_4_0_h_small"), n_layers=10,
+                  experts_held=18)
+    model = Model(cfg)
+    engine = ServingEngine(model, None, None, max_batch=B, prompt_len=32,
+                           max_new_tokens=8)
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          model.param_shapes())
+    toks = _sds(one_chip, (B, 32), jnp.int32)
+    compiled = engine._generate.lower(params, toks).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 6.5 * 10**9    # 6.53 GB of bf16
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

@@ -96,6 +96,10 @@ def test_layer_groups_patterns():
     assert len(g) == 1 and n == 64 and g[0].kind == "mamba"
     g, n = layer_groups(get_config("deepseek_67b"))
     assert len(g) == 1 and n == 95
+    g, n = layer_groups(get_config("granite_4_0_h_small"))
+    assert len(g) == 10 and n == 4
+    assert [s.kind for s in g].count("attn") == 1 and g[5].kind == "attn"
+    assert all(s.mlp == "moe" for s in g)
 
 
 def test_param_counts_plausible():
@@ -109,6 +113,7 @@ def test_param_counts_plausible():
         "jamba_v0_1_52b": (45e9, 58e9),
         "llava_next_mistral_7b": (6.5e9, 8.0e9),
         "falcon_mamba_7b": (6.5e9, 8.5e9),
+        "granite_4_0_h_small": (30e9, 34e9),    # 32B-A9B
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
@@ -116,14 +121,16 @@ def test_param_counts_plausible():
     # kimi active ≈ 32 B
     a = get_config("kimi_k2_1t_a32b").active_param_count()
     assert 25e9 <= a <= 45e9
+    a = get_config("granite_4_0_h_small").active_param_count()
+    assert 8e9 <= a <= 10e9
 
 
 def test_cell_accounting():
-    """40 nominal cells = 32 runnable + 8 documented skips."""
+    """44 nominal cells = 35 runnable + 9 documented skips."""
     run = runnable_cells()
     skip = skipped_cells()
-    assert len(run) == 32
-    assert len(skip) == 8
+    assert len(run) == 35
+    assert len(skip) == 9
     assert all(s[1] == "long_500k" for s in skip)
     assert {a for a, s in run if s == "long_500k"} == \
         {"jamba_v0_1_52b", "falcon_mamba_7b"}
