@@ -14,6 +14,12 @@ The end-to-end path (examples/serve_e2e.py):
                  (one store pass, one index delta flush — the device
                  tables sync O(batch) bytes, not O(capacity))
 
+A lookup that finds both hits and misses returns its hits at once and
+leaves its misses, looked up, at the head of the queue: the next step()
+generates them and writes them back, with no lookup of its own. So a hit
+waits for no generate of its own step, and every lookup and insert keeps
+its order. An all-miss lookup generates in the same step.
+
 Latency/queue-depth observations feed the ``AdaptiveController`` so cache
 policies relax under load (§7.5) — on a real deployment this is the same
 code path, just with a bigger mesh under ``Dist``.
@@ -47,6 +53,8 @@ class Request:
     max_new_tokens: int = 16
     arrival: float = 0.0                # submit, time.monotonic
     traced_at: float = 0.0              # submit, on the recorder's clock
+    emb: np.ndarray | None = None       # set when its lookup missed and
+                                        # its generate is still to come
 
 
 @dataclass
@@ -78,6 +86,10 @@ class EngineStats:
     # steps the watchdog flagged as stragglers (wall time > factor × the
     # trailing-median step time) — the serving-side liveness signal.
     straggler_steps: int = 0
+    # hits answered before their lookup's misses were generated, and the
+    # generates so deferred to the next step()
+    hits_before_generate: int = 0
+    deferred_generates: int = 0
 
     @property
     def hit_rate(self) -> float:
@@ -185,17 +197,40 @@ class ServingEngine:
         return rid
 
     def step(self) -> list[Response]:
-        """Serve one batch from the queue. Returns completed responses."""
+        """Serve one step from the queue. Returns completed responses.
+
+        Misses a previous lookup left at the head of the queue are
+        generated, written back and returned. Otherwise the step looks up
+        the next ``max_batch`` requests and returns their hits; their
+        misses are generated in the same step only when nothing hit."""
         if not self.queue:
             return []
-        with self._span("engine_step", batch=min(len(self.queue),
-                                                 self.max_batch)):
-            return self._step_impl()
-
-    def _step_impl(self) -> list[Response]:
+        if self.queue[0].emb is not None:
+            n = next((k for k, r in enumerate(self.queue) if r.emb is None),
+                     len(self.queue))
+            misses, self.queue = self.queue[:n], self.queue[n:]
+            with self._span("engine_step", batch=n, phase="generate"):
+                return self._finish(self._generate_misses(misses))
         self.watchdog.step_start()
         batch = self.queue[:self.max_batch]
         self.queue = self.queue[self.max_batch:]
+        with self._span("engine_step", batch=len(batch)) as sp:
+            hits, misses = self._lookup(batch)
+            if hits and misses:
+                # The hits go back now; the misses wait, looked up, at
+                # the head of the queue for the next step's generate.
+                sp.set(phase="lookup")
+                self.queue[:0] = misses
+                self.stats.hits_before_generate += len(hits)
+                self.stats.deferred_generates += 1
+                self._stamp(hits)
+                return [resp for resp, _ in hits]
+            sp.set(phase="lookup+generate" if misses else "lookup")
+            return self._finish(hits + self._generate_misses(misses))
+
+    def _lookup(self, batch: list[Request]):
+        """Embed and look up ``batch``: (hits as (response, request)
+        pairs, misses with their embeddings set)."""
         if self.obs is not None:
             # each request's wait for the step in flight, submit to now,
             # on the recorder's clock (latencies stay on time.monotonic)
@@ -212,69 +247,81 @@ class ServingEngine:
             self.stats.search_hops += ls.get("hops", 0)
             self.stats.rows_gathered += ls.get("rows_gathered", 0)
 
-        # Latencies are stamped when the step returns (below): a hit is
-        # handed back with its step's misses, not when it was found.
-        responses: list[Response] = []
-        served: list[Request] = []
-        misses: list[int] = []
-        for i, (req, res) in enumerate(zip(batch, results)):
+        hits: list[tuple[Response, Request]] = []
+        misses: list[Request] = []
+        for req, res, emb in zip(batch, results, embs):
             if res.hit:
-                responses.append(Response(req.req_id, res.response, None,
-                                          True, 0.0, req.category,
-                                          reason=res.reason))
-                served.append(req)
+                hits.append((Response(req.req_id, res.response, None, True,
+                                      0.0, req.category, reason=res.reason),
+                             req))
                 self.stats.served += 1
                 self.stats.cache_hits += 1
                 self.stats.count_reason(res.reason)
             else:
-                misses.append(i)
+                req.emb = emb
+                misses.append(req)
+        return hits, misses
 
-        if misses:
-            # Pad the miss batch to the search's power-of-two bucket so
-            # every miss count shares one compiled generate program; the
-            # padding rows (all-zero prompts) are sliced off.
-            toks = np.zeros((_bucket_batch(len(misses)), self.prompt_len),
-                            np.int32)
-            for j, i in enumerate(misses):
-                p = batch[i].prompt_tokens[:self.prompt_len]
-                toks[j, :len(p)] = p
-            prompt_tokens = sum(min(len(batch[i].prompt_tokens),
-                                    self.prompt_len) for i in misses)
-            attrs = {}
-            if self.obs is not None:
-                attrs["state_mb"] = self._state_mb(toks.shape[0])
-            with self._span("model_generate", batch=len(misses),
-                            bucket=toks.shape[0],
-                            prompt_tokens=prompt_tokens, **attrs):
-                out = np.asarray(
-                    self._generate(self.params, jnp.asarray(toks)))
-            out = out[:len(misses)]
-            texts = ["tok:" + ",".join(map(str, out[j]))
-                     for j in range(len(misses))]
-            # one batched write-back for every miss in this step
-            self.cache.insert_batch(
-                embs[misses], [batch[i].category for i in misses],
-                [batch[i].text for i in misses], texts)
-            for j, i in enumerate(misses):
-                req = batch[i]
-                responses.append(Response(req.req_id, texts[j], out[j],
-                                          False, 0.0, req.category,
-                                          reason="model"))
-                served.append(req)
-                self.stats.served += 1
-                self.stats.model_tokens += out.shape[1]
-                self.stats.count_reason("model")
+    def _generate_misses(self, misses: list[Request]
+                         ) -> list[tuple[Response, Request]]:
+        """Generate looked-up misses, write them back in one insert, and
+        return them as (response, request) pairs."""
+        if not misses:
+            return []
+        # Pad the miss batch to the search's power-of-two bucket so
+        # every miss count shares one compiled generate program; the
+        # padding rows (all-zero prompts) are sliced off.
+        toks = np.zeros((_bucket_batch(len(misses)), self.prompt_len),
+                        np.int32)
+        for j, req in enumerate(misses):
+            p = req.prompt_tokens[:self.prompt_len]
+            toks[j, :len(p)] = p
+        prompt_tokens = sum(min(len(r.prompt_tokens), self.prompt_len)
+                            for r in misses)
+        attrs = {}
+        if self.obs is not None:
+            attrs["state_mb"] = self._state_mb(toks.shape[0])
+        with self._span("model_generate", batch=len(misses),
+                        bucket=toks.shape[0],
+                        prompt_tokens=prompt_tokens, **attrs):
+            out = np.asarray(self._generate(self.params, jnp.asarray(toks)))
+        out = out[:len(misses)]
+        texts = ["tok:" + ",".join(map(str, out[j]))
+                 for j in range(len(misses))]
+        # one batched write-back for every miss of the lookup
+        self.cache.insert_batch(
+            np.stack([r.emb for r in misses]), [r.category for r in misses],
+            [r.text for r in misses], texts)
+        served = []
+        for j, req in enumerate(misses):
+            served.append((Response(req.req_id, texts[j], out[j], False,
+                                    0.0, req.category, reason="model"),
+                           req))
+            self.stats.served += 1
+            self.stats.model_tokens += out.shape[1]
+            self.stats.count_reason("model")
+        return served
+
+    def _stamp(self, served: list[tuple[Response, Request]]) -> None:
+        """Stamp each response's latency, submit to now, as it is handed
+        back; the controller observes the model's answers."""
         t_end = time.monotonic()
-        for resp, req in zip(responses, served):
+        for resp, req in served:
             resp.latency_ms = (t_end - req.arrival) * 1e3
             self.stats.total_latency_ms += resp.latency_ms
             if self.controller is not None and not resp.cached:
                 self.controller.observe(self.model_name, LoadSignal(
                     latency_ms=resp.latency_ms,
                     queue_depth=len(self.queue)))
+
+    def _finish(self, served: list[tuple[Response, Request]]
+                ) -> list[Response]:
+        """Hand back a step that ends its lookup's work: stamp it, and
+        end the watchdog's step, begun at the lookup."""
+        self._stamp(served)
         self.watchdog.step_end()
         self.stats.straggler_steps = self.watchdog.straggler_events
-        return responses
+        return [resp for resp, _ in served]
 
     def drain(self) -> list[Response]:
         out = []

@@ -175,9 +175,10 @@ def test_adaptive_integration_relaxes_threshold(small_model, rng):
 
 
 def test_engine_stamps_latency_when_the_step_returns(small_model, rng):
-    """A hit found before its step's misses are generated is handed back
-    with them, at step end: every response of a step carries the time
-    to that end, and the engine's latency total is their sum."""
+    """Each response carries the time to the end of the step that hands
+    it back: a hit found beside misses returns from its lookup step,
+    before the misses' generate; the misses from the next step, after
+    it. The engine's latency total is their sum."""
     import time as _time
 
     cfg, model, params = small_model
@@ -198,13 +199,13 @@ def test_engine_stamps_latency_when_the_step_returns(small_model, rng):
     t0 = _time.monotonic()
     eng.submit("what is a closure", "code_generation", toks)
     eng.submit("a brand new uncached question", "code_generation", toks)
-    res = eng.step()
+    (hit,) = eng.step()
+    hit_wall_ms = (_time.monotonic() - t0) * 1e3
+    (miss,) = eng.step()
     wall_ms = (_time.monotonic() - t0) * 1e3
-    hit, miss = sorted(res, key=lambda r: not r.cached)
     assert hit.cached and not miss.cached
-    assert 300 <= hit.latency_ms <= wall_ms
-    assert hit.latency_ms >= miss.latency_ms       # submitted first
-    assert hit.latency_ms - miss.latency_ms < 50
+    assert hit.latency_ms <= hit_wall_ms < 300
+    assert 300 <= miss.latency_ms <= wall_ms
     assert eng.stats.total_latency_ms - total0 == pytest.approx(
         hit.latency_ms + miss.latency_ms)
 
