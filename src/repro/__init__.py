@@ -9,7 +9,8 @@ Semantic Caching for Heterogeneous LLM Workloads" (CS.DB 2025):
 - ``repro.models``   — LLM substrate (dense / MoE / SSM / hybrid / enc-dec).
 - ``repro.kernels``  — Pallas TPU kernels for the cache + attention hot spots.
 - ``repro.serving``  — batched serving engine, multi-model router, simulator.
-- ``repro.launch``   — production mesh, multi-pod dry-run, train/serve drivers.
+- ``repro.launch``   — meshes, sharding rules, the serving driver and the
+                       data plane's mesh dry-run.
 """
 
 __version__ = "1.0.0"

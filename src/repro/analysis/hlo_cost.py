@@ -17,7 +17,7 @@ optimized HLO *with loop multiplication*:
   * collective bytes/counts are accumulated per kind with multipliers.
 
 Shapes in optimized HLO are post-SPMD = per-device, so all totals are
-per-device. This is the measurement backing EXPERIMENTS.md §Roofline.
+per-device. The contracts and ``launch/cache_dryrun`` read it.
 """
 
 from __future__ import annotations

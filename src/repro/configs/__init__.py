@@ -1,15 +1,11 @@
-"""Assigned architecture configs (+ shapes).
+"""Assigned architecture configs.
 
-``get_config(arch_id)`` returns the exact assigned ``ArchConfig``;
-``SHAPES`` maps shape ids to (seq_len, global_batch, step kind);
-``runnable_cells()`` enumerates the dry-run matrix with documented skips
-(DESIGN.md §5).
+``get_config(arch_id)`` returns the exact assigned ``ArchConfig``.
 """
 
 from __future__ import annotations
 
 import importlib
-from dataclasses import dataclass
 
 from repro.models.config import ArchConfig
 
@@ -43,26 +39,6 @@ ALIASES = {
 }
 
 
-@dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str           # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
-
-# long_500k needs sub-quadratic attention (DESIGN.md §5): run only for the
-# SSM/hybrid archs; everything else is recorded as an explicit skip.
-LONG_CONTEXT_ARCHS = {"jamba_v0_1_52b", "falcon_mamba_7b"}
-
-
 def get_config(arch_id: str) -> ArchConfig:
     mod_name = ALIASES.get(arch_id, arch_id).replace("-", "_").replace(".", "_")
     mod = importlib.import_module(f"repro.configs.{mod_name}")
@@ -71,18 +47,3 @@ def get_config(arch_id: str) -> ArchConfig:
 
 def all_configs() -> dict[str, ArchConfig]:
     return {a: get_config(a) for a in ARCH_IDS}
-
-
-def runnable_cells() -> list[tuple[str, str]]:
-    cells = []
-    for arch in ARCH_IDS:
-        for shape in ("train_4k", "prefill_32k", "decode_32k"):
-            cells.append((arch, shape))
-        if arch in LONG_CONTEXT_ARCHS:
-            cells.append((arch, "long_500k"))
-    return cells
-
-
-def skipped_cells() -> list[tuple[str, str, str]]:
-    return [(arch, "long_500k", "quadratic-attention")
-            for arch in ARCH_IDS if arch not in LONG_CONTEXT_ARCHS]

@@ -18,6 +18,5 @@ CONFIG = ArchConfig(
     vocab_size=102400,
     rope_theta=10000.0,
     remat="dots",
-    grad_accum=4,
     source="arXiv:2401.02954; hf",
 )

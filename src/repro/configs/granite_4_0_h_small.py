@@ -51,6 +51,5 @@ CONFIG = ArchConfig(
     norm_eps=1e-5,
     capacity_factor=1.5,
     remat="dots",
-    grad_accum=2,
     source="hf:ibm-granite/granite-4.0-h-small config.json",
 )

@@ -35,6 +35,5 @@ CONFIG = ArchConfig(
     ssm_expand=2,
     capacity_factor=1.5,
     remat="dots",
-    grad_accum=2,
     source="arXiv:2403.19887; hf",
 )

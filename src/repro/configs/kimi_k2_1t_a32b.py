@@ -3,8 +3,7 @@
 61L d_model=7168 64H (GQA kv=8) d_ff=2048 vocab=163840, MoE 384e top-8
 [arXiv:2501.kimi2; unverified]. All layers MoE with expert d_ff=2048;
 expert-parallel over the data axis, expert-ffn over the model axis.
-grad_accum=8 keeps the routing buffers ≲1.5 GB/device at train_4k;
-prefill_32k is chunked (vLLM-style) for the same reason.
+Prefill is chunked (vLLM-style) to bound the routing buffers.
 """
 
 from repro.models.config import ArchConfig
@@ -26,9 +25,7 @@ CONFIG = ArchConfig(
     rope_theta=50000.0,
     capacity_factor=1.25,
     remat="full",
-    grad_accum=8,
     prefill_chunk=4096,
-    opt_state_dtype="int8",   # 2 B/param moments: 1T params fit 512×16 GB
 
     source="arXiv:2501.kimi2; unverified",
 )

@@ -5,9 +5,9 @@ static shape math (MoE capacities, padding). ``Dist.single()`` is the
 1-device stand-in used by smoke tests and examples — model code never
 branches on "is distributed", only on axis sizes.
 
-Axis convention (DESIGN.md §4):
+Axis convention:
     pod    — outer data parallelism (slow inter-pod links); optional
-    data   — intra-pod data parallelism / FSDP / MoE expert ownership
+    data   — intra-pod data parallelism / MoE expert ownership
     model  — tensor parallelism (heads, ffn, vocab) / MoE ffn sharding
 """
 

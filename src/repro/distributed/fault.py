@@ -1,59 +1,25 @@
-"""Fault tolerance: preemption handling, straggler watchdog, retry.
-
-``PreemptionHandler`` — SIGTERM/SIGINT → set a flag; the training loop
-checkpoints and exits cleanly at the next step boundary (emergency save).
+"""Fault tolerance for the serving engine: straggler watchdog, retry.
 
 ``StepWatchdog`` — detects stragglers/hangs: if a step exceeds
 ``timeout_factor ×`` the trailing-median step time, a callback fires
 (alert / skip / abort). On a real multi-host deployment the callback wires
 to the cluster manager to evict the slow host and trigger elastic restart;
-here it is exercised by tests and the training driver's logging.
+here ``ServingEngine.step()`` counts it in ``stats.straggler_steps``.
 
-``retry_step`` — bounded retry with re-randomized donation buffers for
-transient device errors (the restart path of checkpoint/restart is covered
-by ``repro.checkpoint``). Backoff between attempts is charged to an
-injectable ``repro.core.clock.Clock`` — a ``SimClock`` makes retry timing
+``retry_step`` — bounded retry for transient device errors. Backoff
+between attempts is charged to an injectable ``repro.core.clock.Clock``
+— a ``SimClock`` makes retry timing
 deterministic and testable, a ``WallClock`` really sleeps; the default
 ``backoff_s=0`` keeps the historical retry-immediately behavior.
 """
 
 from __future__ import annotations
 
-import signal
 import statistics
 import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable
-
-
-class PreemptionHandler:
-    def __init__(self, signals=(signal.SIGTERM, signal.SIGINT)):
-        self._flag = False
-        self._installed = False
-        self._signals = signals
-        self._prev = {}
-
-    def install(self) -> "PreemptionHandler":
-        for s in self._signals:
-            self._prev[s] = signal.signal(s, self._on_signal)
-        self._installed = True
-        return self
-
-    def uninstall(self) -> None:
-        for s, prev in self._prev.items():
-            signal.signal(s, prev)
-        self._installed = False
-
-    def _on_signal(self, signum, frame) -> None:
-        self._flag = True
-
-    @property
-    def preempted(self) -> bool:
-        return self._flag
-
-    def trigger_for_test(self) -> None:
-        self._flag = True
 
 
 @dataclass

@@ -23,7 +23,7 @@ tiling, a jit'd wrapper in ``ops.py``, and a pure-jnp oracle in ``ref.py``:
 Kernels target TPU (MXU-aligned tiles, VMEM budgets, tile-aligned DMAs);
 on the CPU they run with ``interpret=True`` against the oracles, and
 tests/test_chip_compile.py compiles the cache kernels for a described v5e.
-Model code paths default to pure-jnp implementations (clean HLO for the
-dry-run roofline) and switch to kernels with ``use_pallas=True`` on real
-TPUs.
+The models attend and scan in pure jnp; no served path calls the three
+model kernels yet (they are reached through ``ops`` by tests and
+``benchmarks/bench_kernels.py``).
 """

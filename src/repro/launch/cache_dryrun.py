@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.analysis import roofline as rl
+from repro.analysis import hlo_cost
 from repro.core.hnsw import beam_search
 from repro.distributed.context import Dist
 from repro.launch.mesh import make_production_mesh
@@ -120,10 +120,7 @@ def build(impl: str, multi_pod: bool, n_entries: int, batch: int,
     t_compile = time.time() - t0
     cost = {k: float(v) for k, v in (compiled.cost_analysis() or {}).items()
             if isinstance(v, (int, float))}
-    hlo = compiled.as_text()
-    coll = rl.collective_bytes_from_hlo(hlo)
-    from repro.analysis import hlo_cost
-    parsed = hlo_cost.analyze(hlo).to_dict()
+    parsed = hlo_cost.analyze(compiled.as_text()).to_dict()
     mem = compiled.memory_analysis()
     mem_dict = {a: int(getattr(mem, a)) for a in
                 ("argument_size_in_bytes", "output_size_in_bytes",
@@ -140,7 +137,6 @@ def build(impl: str, multi_pod: bool, n_entries: int, batch: int,
         "compile_s": round(t_compile, 2),
         "memory_analysis": mem_dict,
         "cost_analysis": cost,
-        "collectives": coll,
         "hlo_cost": parsed,
         # ideal: stream the (replicated) table once per query batch;
         # the delta flush streams only the dirty rows
@@ -185,7 +181,7 @@ def main():
             byts = cost.get("bytes accessed", 0.0)
             print(f"  flops={flops:.3e} bytes={byts:.3e} "
                   f"mem_ms={byts / 819e9 * 1e3:.3f} "
-                  f"coll={payload['collectives']['total_bytes']:.3e}")
+                  f"coll={payload['hlo_cost']['total_collective_bytes']:.3e}")
 
 
 if __name__ == "__main__":

@@ -1,9 +1,9 @@
-"""Production meshes (DESIGN.md §4).
+"""Production meshes.
 
 ``make_production_mesh`` is a FUNCTION so importing this module never
 touches jax device state. Single pod: 16×16 = 256 chips (data, model).
 Multi-pod: 2×16×16 = 512 chips (pod, data, model) — the pod axis is outer
-data parallelism (or pipeline stages via ``pipeline_over_pod``).
+data parallelism.
 
 All mesh construction routes through ``make_mesh``, which requests Auto
 axes (``jax.sharding.AxisType.Auto``): sharding is propagated by GSPMD

@@ -1,8 +1,8 @@
 """GQA attention: prefill and decode paths.
 
-Pure-jnp formulation (clean HLO for the dry-run roofline; XLA fuses the
-softmax chain). On real TPUs, ``use_pallas=True`` at the model level routes
-through ``repro.kernels.ops.flash_attention`` / ``decode_attention`` instead.
+Pure-jnp formulation (XLA fuses the softmax chain). The Pallas kernels
+``repro.kernels.ops.flash_attention`` / ``decode_attention`` are not wired
+in.
 
 Supports: grouped KV heads, sliding-window + causal masks with absolute
 positions (``kv_offset`` for chunked prefill), attention logit softcap

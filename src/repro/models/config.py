@@ -78,12 +78,10 @@ class ArchConfig:
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     remat: str = "none"               # none | dots | full
-    grad_accum: int = 1               # training microbatches (MoE memory)
     prefill_chunk: int | None = None  # chunked prefill (vLLM-style)
     logits_fp32: bool = True
     loss_chunk: int = 512             # sequence-chunked cross-entropy
     scan_layers: bool = True          # lax.scan over stacked layer params
-    opt_state_dtype: str = "fp32"     # fp32 | bf16 | int8 (Adam moments)
 
     # --- metadata ----------------------------------------------------------------------
     source: str = ""                  # provenance tag from the assignment
@@ -151,7 +149,7 @@ class ArchConfig:
         return self.sliding_window
 
     def param_count(self) -> int:
-        """Analytic parameter count (drives 6·N·D MODEL_FLOPS)."""
+        """Analytic parameter count."""
         d, ff, V = self.d_model, self.d_ff, self.vocab_size
         n = 0
         n += V * d * (1 if self.tie_embeddings else 2)        # embed (+ head)
@@ -212,7 +210,7 @@ class ArchConfig:
             ssm_d_state=8, ssm_expand=2,
             d_ff_shared=64 if self.d_ff_shared else 0, ssm_chunk=16,
             experts_held=0, experts_first=0,
-            grad_accum=1, prefill_chunk=None, loss_chunk=64,
+            prefill_chunk=None, loss_chunk=64,
         )
         kw.update(overrides)
         return replace(self, **kw)

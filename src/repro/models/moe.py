@@ -20,8 +20,8 @@ Two implementations sharing one routing function:
         (ffn partial sums) → all_to_all back → weighted scatter-combine.
 
     Buffer bytes per device ≈ n_data·C·d ≈ T_loc·top_k·capacity·d — kept
-    small by training with ``grad_accum`` microbatches (configs set this
-    for kimi-k2). Experts are zero-padded to a multiple of ``n_data``
+    small by chunked prefill (``cfg.prefill_chunk``; kimi-k2 sets it).
+    Experts are zero-padded to a multiple of ``n_data``
     (router logits for padding = −inf, so they never receive tokens).
 
 The paper's technique (semantic caching) sits in front of any of this;

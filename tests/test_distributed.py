@@ -1,18 +1,19 @@
-"""Distribution: sharding plans, MoE EP equivalence, multi-device train
-step, mesh construction. Multi-device tests run in subprocesses so the
-main process keeps its single-CPU jax runtime."""
+"""Distribution: sharding plans, MoE EP equivalence, sharded loss
+equivalence, mesh construction. Multi-device tests run in subprocesses so
+the main process keeps its single-CPU jax runtime."""
 
-import json
 import subprocess
 import sys
 import textwrap
 
+import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_config
+from repro.configs import ARCH_IDS, get_config
 from repro.distributed.context import Dist
 from repro.launch import sharding as shd
+from repro.models.model import Model
 
 
 def run_sub(code: str, devices: int = 8, timeout: int = 480) -> str:
@@ -28,25 +29,24 @@ def run_sub(code: str, devices: int = 8, timeout: int = 480) -> str:
     return out.stdout
 
 
-def test_param_plan_rules_single_pod():
-    cfg = get_config("deepseek_67b")
-
-    class FakeDist(Dist):
-        pass
-    # synthesize a 16×16 dist without devices: mesh=None blocks axis sizes,
-    # so exercise through a subprocess for the real thing; here check the
-    # structural walk with a 1-device dist (everything replicated).
-    dist = Dist.single()
-    plan = shd.param_plan(cfg, dist, training=True)
-    leaves = []
-    def walk(t):
-        if isinstance(t, P):
-            leaves.append(t)
-        elif isinstance(t, dict):
-            for v in t.values():
-                walk(v)
-    walk(plan.params)
-    assert len(leaves) > 5
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_param_plan_rules_single_pod(arch):
+    """The serve plan gives exactly one PartitionSpec per parameter leaf,
+    none longer than its leaf's rank (a rule written for a 2-D leaf that
+    meets a 1-D one, as Mamba-2's ``A_log`` did, fails here), and every
+    leaf has a rule of its own."""
+    cfg = get_config(arch)
+    shapes = jax.eval_shape(Model(cfg).init_params, jax.random.key(0))
+    plan = shd.param_plan(cfg, Dist.single())
+    is_spec = lambda x: isinstance(x, P)
+    assert (jax.tree.structure(plan.params, is_leaf=is_spec)
+            == jax.tree.structure(shapes))
+    specs = jax.tree.leaves(plan.params, is_leaf=is_spec)
+    leaves = jax.tree.leaves(shapes)
+    assert all(is_spec(s) for s in specs)
+    for spec, leaf in zip(specs, leaves):
+        assert len(spec) <= leaf.ndim, (spec, leaf.shape)
+    assert not [n for n in plan.notes if "no rule" in n], plan.notes
 
 
 def test_param_plan_on_real_mesh():
@@ -60,16 +60,19 @@ def test_param_plan_on_real_mesh():
         mesh = make_mesh((4, 2), ("data", "model"))
         dist = Dist.from_mesh(mesh)
         cfg = get_config("deepseek_67b")
-        plan = shd.param_plan(cfg, dist, training=True)
+        plan = shd.param_plan(cfg, dist)
         s = plan.params["stack"]["sub0"]
-        assert s["mix"]["wq"] == P(None, "data", "model", None), s["mix"]["wq"]
-        assert s["mlp"]["w_gate"] == P(None, "data", "model")
-        assert s["mlp"]["w_down"] == P(None, "model", "data")
-        assert plan.params["embed"] == P("model", "data")
-        # serving: no fsdp
-        plan_s = shd.param_plan(cfg, dist, training=False)
-        assert plan_s.params["stack"]["sub0"]["mlp"]["w_gate"] == P(None, None, "model")
-        # gemma2: 8 heads don't divide model=2? they do; use granite_moe 24 H % 2 == 0
+        # weights are tensor-parallel over model, replicated over data
+        assert s["mix"]["wq"] == P(None, None, "model", None), s["mix"]["wq"]
+        assert s["mix"]["wo"] == P(None, "model", None, None)
+        assert s["mlp"]["w_gate"] == P(None, None, "model")
+        assert s["mlp"]["w_down"] == P(None, "model", None)
+        assert plan.params["embed"] == P("model", None)
+        # MoE experts are stored over data
+        moe = shd.param_plan(get_config("granite_moe_3b_a800m"), dist)
+        w = moe.params["stack"]["sub0"]["mlp"]
+        assert w["w_gate"] == P(None, ("data",), None, "model"), w["w_gate"]
+        assert w["w_down"] == P(None, ("data",), "model", None)
         print("OK")
     """)
     assert "OK" in out
@@ -103,43 +106,6 @@ def test_moe_ep_matches_dense_on_mesh():
     assert "OK" in out
 
 
-def test_train_step_runs_sharded():
-    out = run_sub("""
-        import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.configs import get_config
-        from repro.distributed.context import Dist
-        from repro.launch import sharding as shd
-        from repro.launch.steps import make_train_step
-        from repro.models.model import Model
-        from repro.optim.adamw import AdamWConfig, init_opt_state
-
-        from repro.launch.mesh import make_mesh
-        mesh = make_mesh((2, 2), ("data", "model"))
-        dist = Dist.from_mesh(mesh)
-        cfg = get_config("granite_moe_3b_a800m").reduced(grad_accum=2)
-        model = Model(cfg, dist)
-        params = model.init_params(jax.random.key(0))
-        opt_cfg = AdamWConfig(lr=1e-3)
-        opt = init_opt_state(params, opt_cfg)
-        plan = shd.param_plan(cfg, dist, training=True)
-        pshard = plan.shardings(mesh)
-        params = jax.device_put(params, pshard)
-        step = jax.jit(make_train_step(model, opt_cfg), donate_argnums=(0, 1))
-        rng = np.random.default_rng(0)
-        batch = {"tokens": jnp.asarray(rng.integers(1, cfg.vocab_size, (8, 64))),
-                 "labels": jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 64)))}
-        p2, o2, met = step(params, opt, batch)
-        loss1 = float(met["loss"])
-        p3, o3, met = step(p2, o2, batch)
-        loss2 = float(met["loss"])
-        assert np.isfinite(loss1) and np.isfinite(loss2)
-        assert loss2 < loss1 + 0.1  # moving
-        print("OK", loss1, loss2)
-    """)
-    assert "OK" in out
-
-
 def test_production_mesh_shapes():
     out = run_sub("""
         from repro.launch.mesh import make_production_mesh
@@ -150,22 +116,6 @@ def test_production_mesh_shapes():
         print("OK")
     """, devices=512)
     assert "OK" in out
-
-
-def test_dryrun_results_complete():
-    """The committed dry-run results must cover all 64 runnable compiles."""
-    import glob
-    import os
-    root = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                        "results", "dryrun")
-    files = glob.glob(os.path.join(root, "*.json"))
-    if not files:
-        pytest.skip("dry-run results not generated yet")
-    assert len(files) >= 64
-    for f in files[:4]:
-        with open(f) as fh:
-            payload = json.load(fh)
-        assert payload["cost_analysis"].get("flops", 0) > 0
 
 
 def test_sharded_loss_equals_single_device():
@@ -195,50 +145,12 @@ def test_sharded_loss_equals_single_device():
         mesh = make_mesh((2, 2), ("data", "model"))
         dist = Dist.from_mesh(mesh)
         m_mesh = Model(cfg, dist)
-        pshard = shd.param_plan(cfg, dist, training=True).shardings(mesh)
+        pshard = shd.param_plan(cfg, dist).shardings(mesh)
         params_sharded = jax.device_put(params, pshard)
         loss_mesh, _ = jax.jit(m_mesh.loss_fn)(params_sharded, batch)
 
         d = abs(float(loss_single) - float(loss_mesh))
         assert d < 5e-3, (float(loss_single), float(loss_mesh))
         print("OK", float(loss_single), float(loss_mesh))
-    """)
-    assert "OK" in out
-
-
-def test_pipeline_over_pod_matches_sequential():
-    """GPipe-over-pod (GSPMD roll schedule) must equal the sequential
-    stack's loss exactly — same math, different schedule."""
-    out = run_sub("""
-        import numpy as np, jax, jax.numpy as jnp
-        from repro.configs import get_config
-        from repro.distributed.context import Dist
-        from repro.launch import sharding as shd
-        from repro.launch.pipeline import (make_pp_loss, pp_stack_specs,
-                                           reshape_stack_for_pp)
-        from repro.models.model import Model
-
-        cfg = get_config("llama3_2_3b").reduced(n_layers=4, d_model=64,
-                                                n_heads=4, n_kv_heads=2,
-                                                head_dim=16, vocab_size=256)
-        rng = np.random.default_rng(0)
-        batch = {"tokens": jnp.asarray(rng.integers(1, 256, (8, 32))),
-                 "labels": jnp.asarray(rng.integers(0, 256, (8, 32)))}
-
-        m0 = Model(cfg, None)
-        params = m0.init_params(jax.random.key(0))
-        loss_seq, _ = jax.jit(m0.loss_fn)(params, batch)
-
-        from repro.launch.mesh import make_mesh
-        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
-        dist = Dist.from_mesh(mesh)
-        m = Model(cfg, dist)
-        pp_params = dict(params)
-        pp_params["stack"] = reshape_stack_for_pp(params["stack"], 2)
-        loss_fn = make_pp_loss(m, n_micro=4)
-        loss_pp, _ = jax.jit(loss_fn)(pp_params, batch)
-        d = abs(float(loss_seq) - float(loss_pp))
-        assert d < 2e-3, (float(loss_seq), float(loss_pp))
-        print("OK", float(loss_seq), float(loss_pp))
     """)
     assert "OK" in out

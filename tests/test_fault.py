@@ -1,9 +1,8 @@
-"""Fault tolerance: watchdog, preemption, retry."""
+"""Fault tolerance: watchdog, retry."""
 
 import pytest
 
-from repro.distributed.fault import (PreemptionHandler, StepWatchdog,
-                                     retry_step)
+from repro.distributed.fault import StepWatchdog, retry_step
 
 
 def test_watchdog_flags_stragglers():
@@ -23,40 +22,6 @@ def test_watchdog_needs_history():
     wd = StepWatchdog(min_history=5)
     wd.observe_for_test(10.0)     # first step slow (compile) — no event
     assert wd.straggler_events == 0
-
-
-def test_preemption_flag_via_trigger():
-    h = PreemptionHandler().install()
-    assert not h.preempted
-    h.trigger_for_test()
-    assert h.preempted
-    h.uninstall()
-
-
-def test_preemption_triggers_emergency_checkpoint(tmp_path):
-    """SIGTERM-style preemption mid-run → checkpoint written + clean exit."""
-    from repro.configs import get_config
-    from repro.launch.train import run_training
-    import repro.distributed.fault as fault
-
-    cfg = get_config("llama3_2_3b").reduced(n_layers=2, d_model=64,
-                                            vocab_size=256)
-    orig_install = fault.PreemptionHandler.install
-
-    def install_and_fire(self):
-        orig_install(self)
-        self.trigger_for_test()
-        return self
-    fault.PreemptionHandler.install = install_and_fire
-    try:
-        res = run_training(cfg, steps=50, batch=2, seq=32,
-                           ckpt_dir=str(tmp_path), ckpt_every=1000,
-                           log=lambda *_: None)
-    finally:
-        fault.PreemptionHandler.install = orig_install
-    assert res["steps_run"] == 1          # stopped at first boundary
-    from repro.checkpoint import latest_step
-    assert latest_step(str(tmp_path)) == 1
 
 
 def test_retry_step_retries_then_raises():

@@ -7,8 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import ARCH_IDS, SHAPES, get_config, runnable_cells, \
-    skipped_cells
+from repro.configs import ARCH_IDS, get_config
 from repro.models import Model
 from repro.models.model import padded_vocab
 from repro.models.transformer import layer_groups
@@ -123,18 +122,6 @@ def test_param_counts_plausible():
     assert 25e9 <= a <= 45e9
     a = get_config("granite_4_0_h_small").active_param_count()
     assert 8e9 <= a <= 10e9
-
-
-def test_cell_accounting():
-    """44 nominal cells = 35 runnable + 9 documented skips."""
-    run = runnable_cells()
-    skip = skipped_cells()
-    assert len(run) == 35
-    assert len(skip) == 9
-    assert all(s[1] == "long_500k" for s in skip)
-    assert {a for a, s in run if s == "long_500k"} == \
-        {"jamba_v0_1_52b", "falcon_mamba_7b"}
-    assert len(run) + len(skip) == len(ARCH_IDS) * len(SHAPES)
 
 
 def test_ragged_dot_cotangents_keep_operand_dtypes():
